@@ -1,12 +1,16 @@
 """ctypes bindings to the native core (build/libparsec_core.so).
 
-Auto-builds via `make` when the shared library is missing or older than its
-sources.  All Python→native traffic goes through this module; keep the ABI in
-sync with native/parsec_core.h.
+Auto-builds via `make` when the shared library is missing or was built from
+other sources: freshness is a hash of native/* stored beside the library, not
+mtimes, so a build/ copied from elsewhere rebuilds from the committed sources.
+All Python→native traffic goes through this module; keep the ABI in sync with
+native/parsec_core.h.
 """
 from __future__ import annotations
 
 import ctypes as C
+import fcntl
+import hashlib
 import os
 import subprocess
 
@@ -15,14 +19,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # instrumented, debug, ...) without touching the default build tree.
 _LIB_PATH = os.environ.get("PTC_NATIVE_LIB") or \
     os.path.join(_REPO, "build", "libparsec_core.so")
-_SOURCES = [
-    os.path.join(_REPO, "native", "core.cpp"),
-    os.path.join(_REPO, "native", "sched.cpp"),
-    os.path.join(_REPO, "native", "comm.cpp"),
-    os.path.join(_REPO, "native", "parsec_core.h"),
-    os.path.join(_REPO, "native", "runtime_internal.h"),
-    os.path.join(_REPO, "native", "lockfree.h"),
-]
+_SRC_DIR = os.path.join(_REPO, "native")
 
 # hook protocol (parsec_core.h)
 HOOK_DONE = 0
@@ -93,22 +90,45 @@ OP_SHL = 23
 OP_SHR = 24
 
 
-def _needs_build() -> bool:
+def source_hash() -> str:
+    """sha256 over the names and bytes of every file in native/."""
+    h = hashlib.sha256()
+    for name in sorted(os.listdir(_SRC_DIR)):
+        path = os.path.join(_SRC_DIR, name)
+        if os.path.isfile(path):
+            with open(path, "rb") as f:
+                h.update(name.encode() + b"\0" + f.read())
+    return h.hexdigest()
+
+
+SOURCE_HASH = source_hash()
+
+
+def _ensure_built() -> None:
+    """(Re)build the core unless the library beside its hash file was
+    built from exactly these sources.  Under a file lock: concurrent
+    importers (test workers) build once, and none loads a half-written
+    library."""
     if os.environ.get("PTC_NATIVE_LIB"):
-        return False  # instrumented override: its builder owns freshness
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(os.path.getmtime(s) > lib_mtime for s in _SOURCES
-               if os.path.exists(s))
+        return  # instrumented override: its builder owns freshness
+    stamp = _LIB_PATH + ".srchash"
+    os.makedirs(os.path.dirname(_LIB_PATH), exist_ok=True)
+    with open(_LIB_PATH + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        try:
+            with open(stamp) as f:
+                built = f.read().strip()
+        except OSError:
+            built = None
+        if built == SOURCE_HASH and os.path.exists(_LIB_PATH):
+            return
+        # -B: make's own mtime test would trust a copied library
+        subprocess.run(["make", "-s", "-B"], cwd=_REPO, check=True)
+        with open(stamp, "w") as f:
+            f.write(SOURCE_HASH + "\n")
 
 
-def _build() -> None:
-    subprocess.run(["make", "-s"], cwd=_REPO, check=True)
-
-
-if _needs_build():
-    _build()
+_ensure_built()
 
 lib = C.CDLL(_LIB_PATH)
 

@@ -23,6 +23,11 @@ from ..device.tpu import TpuDevice
 
 from ._util import as_device_list
 
+# Precision of every matmul the tile and panel kernels issue: fp32.  XLA's
+# default on a TPU is one bf16 pass for fp32 operands, and a v5e run at it
+# missed the sqrt(N)*eps32 componentwise check ~180x (3.9e-3; PR 21).
+MATMUL_PRECISION = "highest"
+
 
 # ---------------------------------------------------------------- kernels
 # module-level so their identity is stable: jax.jit keeps ONE compiled
@@ -56,19 +61,22 @@ def k_trsm_mm(linv, c):
     """TRSM as GEMM: X L^T = C  ->  X = C inv(L)^T."""
     import jax
     return jax.lax.dot_general(c, linv, (((1,), (1,)), ((), ())),
-                               preferred_element_type=c.dtype)
+                               preferred_element_type=c.dtype,
+                               precision=MATMUL_PRECISION)
 
 
 def k_syrk(a, t):
     import jax
     return t - jax.lax.dot_general(a, a, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=t.dtype)
+                                   preferred_element_type=t.dtype,
+                                   precision=MATMUL_PRECISION)
 
 
 def k_gemm(a, b, c):
     import jax
     return c - jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=c.dtype)
+                                   preferred_element_type=c.dtype,
+                                   precision=MATMUL_PRECISION)
 
 
 def build_potrf(ctx: pt.Context, A: TwoDimBlockCyclic,
@@ -260,7 +268,8 @@ def k_panel_factor(p, ks):
     linv = jax.scipy.linalg.solve_triangular(
         l, jnp.eye(nb, dtype=p.dtype), lower=True)
     x = jax.lax.dot_general(p, linv, (((1,), (1,)), ((), ())),
-                            preferred_element_type=p.dtype)
+                            preferred_element_type=p.dtype,
+                            precision=MATMUL_PRECISION)
     rows = jnp.arange(p.shape[0], dtype=ks.dtype)[:, None]
     x = jnp.where(rows >= off, x, jnp.zeros((), p.dtype))
     return jax.lax.dynamic_update_slice(x, l, (off, 0))
@@ -272,7 +281,8 @@ def k_panel_update(pk, js, pj):
     off = js[0] * nb
     bj = jax.lax.dynamic_slice(pk, (off, 0), (nb, nb))
     return pj - jax.lax.dot_general(pk, bj, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=pj.dtype)
+                                    preferred_element_type=pj.dtype,
+                                    precision=MATMUL_PRECISION)
 
 
 def _register_pidx(ctx: pt.Context, A: TwoDimBlockCyclic, name: str):

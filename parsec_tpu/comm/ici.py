@@ -34,7 +34,6 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils.jaxcompat import shard_map
 
 
 def device_transfer(arr, dst_device):
@@ -71,8 +70,9 @@ class PermuteEngine:
             def body(xs):
                 return lax.ppermute(xs, self.axis, perm)
 
-            f = jax.jit(shard_map(body, mesh=self.mesh, in_specs=pspec,
-                                  out_specs=pspec))
+            f = jax.jit(jax.shard_map(body, mesh=self.mesh,
+                                      in_specs=pspec, out_specs=pspec,
+                                      check_vma=False))
             self._progs[key] = f
         return f
 

@@ -6,6 +6,10 @@ Spawns N copies of `script.py` with PTC_RANK / PTC_WORLD / PTC_PORT set;
 the script calls `parsec_tpu.comm.init(ctx)` to join the mesh.  Mirrors
 the reference's `${MPI_TEST_CMD_LIST} <nproc>` test template
 (tests/CMakeLists.txt:41-57, SURVEY.md §4).
+
+Only one process may hold a TPU, so the launched ranks run JAX on the CPU
+backend (JAX_PLATFORMS=cpu).  Ranks that drive chips are colocated in ONE
+process instead (`Context.comm_set_colocated`; chip_smoke.py --chips 4).
 """
 import argparse
 import os
@@ -48,7 +52,7 @@ def main(argv=None):
     procs = []
     for r in range(opts.np):
         env = dict(os.environ, PTC_RANK=str(r), PTC_WORLD=str(opts.np),
-                   PTC_PORT=str(port))
+                   PTC_PORT=str(port), JAX_PLATFORMS="cpu")
         procs.append(subprocess.Popen(
             [sys.executable, opts.script, *opts.args], env=env))
     rc = 0

@@ -26,6 +26,19 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_BIG = -1.0e30
 
 
+def for_backend(call, interpret: Optional[bool]):
+    """`call(interpret)` with interpret mode chosen by the backend the
+    program is LOWERED for when `interpret` is None: the Mosaic kernel
+    for a TPU, the Pallas interpreter for any other backend.  Decided at
+    lowering (lax.platform_dependent), never from jax.devices(): a
+    program compiled for a described TPU from a CPU process still gets
+    the real kernel."""
+    if interpret is not None:
+        return call(interpret)
+    return jax.lax.platform_dependent(tpu=lambda: call(False),
+                                      default=lambda: call(True))
+
+
 def _cdiv(a: int, b: int) -> int:
     return (a + b - 1) // b
 
@@ -81,14 +94,14 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int,
-               block_k: int, interpret: bool):
+               block_k: int, interpret: Optional[bool]):
     bh, lq, d = q.shape
     lk = k.shape[1]
     nq, nk = _cdiv(lq, block_q), _cdiv(lk, block_k)
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, nk=nk)
-    return pl.pallas_call(
+    return for_backend(lambda interp: pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
         in_specs=[
@@ -103,8 +116,8 @@ def _flash_fwd(q, k, v, causal: bool, scale: float, block_q: int,
             pltpu.VMEM((block_q, 128), jnp.float32),  # running sum
             pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
         ],
-        interpret=interpret,
-    )(q, k, v)
+        interpret=interp,
+    )(q, k, v), interpret)
 
 
 def _reference(q, k, v, causal, scale):
@@ -146,14 +159,12 @@ def flash_attention(q, k, v, causal: bool = False,
                     interpret: Optional[bool] = None):
     """Fused blockwise attention.  q,k,v: [B, L, H, D] -> [B, L, H, D].
 
-    `interpret=None` auto-selects: real Mosaic lowering on TPU, the
-    Pallas interpreter elsewhere (tests on the virtual CPU mesh).  Falls
+    `interpret=None` lets the backend being lowered for decide
+    (for_backend): Mosaic on a TPU, the Pallas interpreter elsewhere.  Falls
     back to the jnp reference when L is smaller than one block (the
     kernel would be all padding)."""
     b, l, h, d = q.shape
     scale = (d ** -0.5) if scale is None else scale
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
     if l < block_q or l < block_k:
         return _reference(
             jnp.reshape(jnp.transpose(q, (0, 2, 1, 3)), (b * h, l, d)),

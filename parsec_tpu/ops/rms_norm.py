@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .flash_attention import for_backend
+
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
     x = x_ref[...]
@@ -29,15 +31,15 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
 def _rms_fwd_pallas(x2d, w, eps, block_rows, interpret):
     n, d = x2d.shape
     grid = (n // block_rows,)
-    return pl.pallas_call(
+    return for_backend(lambda interp: pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
                   pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x2d.dtype),
-        interpret=interpret,
-    )(x2d, w)
+        interpret=interp,
+    )(x2d, w), interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
@@ -73,13 +75,11 @@ def rms_norm(x, w, eps: float = 1e-6, block_rows: int = 128,
              interpret: Optional[bool] = None):
     """y = x / sqrt(mean(x^2, -1) + eps) * w over the last dim.
 
-    Any leading shape; `interpret=None` auto-selects (Mosaic on TPU,
-    interpreter elsewhere).  Falls back to plain jnp when the row count
+    Any leading shape; `interpret=None` lets the backend being lowered
+    for decide (for_backend).  Falls back to plain jnp when the row count
     doesn't fill one block, or when the last dim violates the TPU lane
     tiling (d % 128) — Mosaic would reject the kernel on hardware even
     though interpret mode happily runs it."""
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
     d = x.shape[-1]
     lead = x.shape[:-1]
     n = 1

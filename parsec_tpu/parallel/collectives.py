@@ -21,8 +21,8 @@ from typing import Optional
 
 import numpy as np
 
+import jax
 from jax import lax
-from ..utils.jaxcompat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -40,7 +40,8 @@ def ring_permute(x, mesh: Mesh, axis: str, shift: int = 1, shard_dim: int = 0):
     spec[shard_dim] = axis
     pspec = P(*spec)
 
-    @partial(shard_map, mesh=mesh, in_specs=pspec, out_specs=pspec)
+    @partial(jax.shard_map, mesh=mesh, check_vma=False,
+             in_specs=pspec, out_specs=pspec)
     def _f(xs):
         return lax.ppermute(xs, axis, _ring_perm(n, shift))
 
@@ -55,7 +56,8 @@ def seq_all_gather(x, mesh: Mesh, axis: str, shard_dim: int = 0):
     in_spec = P(*spec)
     out_spec = P(*([None] * x.ndim))
 
-    @partial(shard_map, mesh=mesh, in_specs=in_spec, out_specs=out_spec)
+    @partial(jax.shard_map, mesh=mesh, check_vma=False,
+             in_specs=in_spec, out_specs=out_spec)
     def _f(xs):
         return lax.all_gather(xs, axis, axis=shard_dim, tiled=True)
 
@@ -70,7 +72,8 @@ def seq_reduce_scatter(x, mesh: Mesh, axis: str, shard_dim: int = 0):
     out_sp = list(spec)
     out_sp[shard_dim] = axis
 
-    @partial(shard_map, mesh=mesh, in_specs=P(*spec), out_specs=P(*out_sp))
+    @partial(jax.shard_map, mesh=mesh, check_vma=False,
+             in_specs=P(*spec), out_specs=P(*out_sp))
     def _f(xs):
         return lax.psum_scatter(xs, axis, scatter_dimension=shard_dim,
                                 tiled=True)
@@ -88,7 +91,8 @@ def seq_all_to_all(x, mesh: Mesh, axis: str, split_dim: int, concat_dim: int):
     out_sp = [None] * x.ndim
     out_sp[split_dim] = axis
 
-    @partial(shard_map, mesh=mesh, in_specs=P(*in_sp), out_specs=P(*out_sp))
+    @partial(jax.shard_map, mesh=mesh, check_vma=False,
+             in_specs=P(*in_sp), out_specs=P(*out_sp))
     def _f(xs):
         return lax.all_to_all(xs, axis, split_axis=split_dim,
                               concat_axis=concat_dim, tiled=True)
@@ -150,7 +154,8 @@ def all_reduce(x, ctx=None, mesh: Optional[Mesh] = None,
         nd = xs.ndim
         out_spec = P(*([None] * (nd - 1)))
 
-        @partial(shard_map, mesh=mesh, in_specs=P(axis),
+        @partial(jax.shard_map, mesh=mesh, check_vma=False,
+                 in_specs=P(axis),
                  out_specs=out_spec)
         def _f(s):
             return lax.psum(s[0], axis)
@@ -190,7 +195,8 @@ def reduce_scatter(x, ctx=None, mesh: Optional[Mesh] = None,
             flat = np.concatenate(
                 [flat, np.zeros((n, pad), flat.dtype)], axis=1)
 
-        @partial(shard_map, mesh=mesh, in_specs=P(axis, None),
+        @partial(jax.shard_map, mesh=mesh, check_vma=False,
+                 in_specs=P(axis, None),
                  out_specs=P(axis))
         def _f(s):
             return lax.psum_scatter(s[0], axis, scatter_dimension=0,
@@ -216,7 +222,8 @@ def all_gather(x, ctx=None, mesh: Optional[Mesh] = None,
         n = mesh.shape[axis]
         flat = np.asarray(xs).reshape(n, -1)
 
-        @partial(shard_map, mesh=mesh, in_specs=P(axis, None),
+        @partial(jax.shard_map, mesh=mesh, check_vma=False,
+                 in_specs=P(axis, None),
                  out_specs=P(None))
         def _f(s):
             return lax.all_gather(s, axis, axis=0, tiled=True)
@@ -242,7 +249,8 @@ def broadcast(x, root: int = 0, ctx=None, mesh: Optional[Mesh] = None,
         flat = np.asarray(xs).reshape(n, -1)
         shape = xs.shape[1:]
 
-        @partial(shard_map, mesh=mesh, in_specs=P(axis, None),
+        @partial(jax.shard_map, mesh=mesh, check_vma=False,
+                 in_specs=P(axis, None),
                  out_specs=P(None))
         def _f(s):
             return lax.all_gather(s, axis, axis=0, tiled=True)[root]

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.jaxcompat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -89,7 +88,7 @@ def moe_ffn(x, w_gate, w_up, w_down, mesh: Mesh, axis: str = "ep",
 
     ws = P(axis, None, None)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh, check_vma=False,
              in_specs=(xs, P(None, None), ws, ws),
              out_specs=xs)
     def _moe(x_loc, wg, wu_loc, wd_loc):

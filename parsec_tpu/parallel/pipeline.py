@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.jaxcompat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -39,7 +38,8 @@ def gpipe(stage_fn: Callable, stage_params, x_mb, mesh: Mesh,
     p_spec = jax.tree.map(lambda _: P(axis), stage_params)
     rest = P(*([None] * x_mb.ndim))
 
-    @partial(shard_map, mesh=mesh, in_specs=(p_spec, rest),
+    @partial(jax.shard_map, mesh=mesh, check_vma=False,
+             in_specs=(p_spec, rest),
              out_specs=rest)
     def _pipe(params_loc, xs):
         # leading stage dim is 1 on each device — squeeze it away

@@ -19,7 +19,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..utils.jaxcompat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 _NEG_BIG = -1.0e30
@@ -80,7 +79,8 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp",
     n = mesh.shape[axis]
     pspec = spec if spec is not None else P(None, axis, None, None)
 
-    @partial(shard_map, mesh=mesh, in_specs=(pspec, pspec, pspec),
+    @partial(jax.shard_map, mesh=mesh, check_vma=False,
+             in_specs=(pspec, pspec, pspec),
              out_specs=pspec)
     def _ring(q_loc, k_loc, v_loc):
         b, lc, h, _ = q_loc.shape

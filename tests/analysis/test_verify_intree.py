@@ -1,7 +1,7 @@
 """Clean-baseline guard: ptc-verify reports ZERO findings across every
 in-tree graph generator (tools/verify_graphs.py), and completes on the
 largest in-tree graph (potrf at the bench tiling, N=16384 NB=1024 ->
-16x16 tiles per BENCH_r05/BASELINE rung-5 r2) in under 5 s."""
+16x16 tiles, the BASELINE rung-5 r2 grid) in under 5 s."""
 import os
 import sys
 import time
@@ -52,7 +52,7 @@ def test_intree_coverage_exercises_instances():
 
 
 def test_potrf_bench_tiling_under_5s():
-    nt, nb = 16, 1024  # N=16384, NB=1024 (BENCH_r05 rung-5 config)
+    nt, nb = 16, 1024  # N=16384, NB=1024 (BASELINE rung-5 r2 config)
     from parsec_tpu.algos.potrf import build_potrf
     with pt.Context(nb_workers=1) as ctx:
         # verification cost depends only on the TILE GRID (nt x nt);

@@ -1,8 +1,6 @@
 """Transfer-economics harness smoke: tools/testbandwidth.py must run at
-small sizes entirely on loopback and emit schema-valid JSON — the
-tunnel-independent evidence path for transfer claims (VERDICT "What's
-weak" #1/#4).  The full sweep is `make bench-comm`; this validates the
-contract CI relies on."""
+small sizes entirely on loopback and emit schema-valid JSON.  The full
+sweep is `make bench-comm`; this validates the contract CI relies on."""
 import json
 import os
 import subprocess

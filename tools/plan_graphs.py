@@ -3,7 +3,7 @@
 generator — the same GENERATORS table `make verify-graphs` walks — and
 assert the plan baseline: every graph plans CLEAN (no enumeration
 refusal at the default tilings, finite residency/makespan bounds) and
-the potrf bench tiling (NT=16, the BENCH_r05 rung-5 grid) plans inside
+the potrf bench tiling (NT=16, the BASELINE rung-5 r2 grid) plans inside
 its latency budget.
 
 `make plan-graphs` runs this; the tier-1 test

@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Transfer-economics harness (reference roles: tests/apps/pingpong/
 bandwidth.jdf for the transport, tools/gpu/testbandwidth for the device
-staging path) — the project's tunnel-independent way to validate
-dispatch/transfer economics on loopback.
+staging path) — validates dispatch/transfer economics on loopback.
 
 Two SPMD processes over loopback TCP run rank-hopping RW chains whose
 datum is a tile of the given size; every hop is one full cross-rank
@@ -19,9 +18,10 @@ Paths swept (each in its own process pair, selected by env knobs):
   eager   — payloads ride inline in ACTIVATE frames (eager_limit huge)
   rdv     — every payload pulled via GET rendezvous (eager_limit 0);
             payloads above comm.chunk_size stream as pipelined chunks
-  device  — TpuDevice attached (jax CPU backend on loopback, the real
-            chip when PTC_BENCH_TPU=1): payloads ride the PK_DEVICE
-            device data plane (d2h at serve / h2d at deliver)
+  device  — TpuDevice attached (jax CPU backend: each rank is its own
+            process, and only one process may hold a chip): payloads
+            ride the PK_DEVICE device data plane (d2h at serve / h2d at
+            deliver)
 
 Per path the harness fits  t(size) = fixed_overhead + size * per_byte
 by least squares over the per-size minima and reports both legs — the
@@ -78,9 +78,7 @@ def _worker(rank, port, sizes, hops, reps, path, env, q):
     try:
         for k, v in env.items():
             os.environ[k] = v
-        import jax
-        if not os.environ.get("PTC_BENCH_TPU"):
-            jax.config.update("jax_platforms", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"  # rank processes stay off the chip
         import parsec_tpu as pt
 
         ctx = pt.Context(nb_workers=1)
@@ -237,8 +235,7 @@ def main():
         **host_provenance(threads=2 * 2),
         "meta": {"hops": hops, "reps": reps, "sizes": sizes,
                  "nodes": 2,
-                 "platform": ("tpu" if os.environ.get("PTC_BENCH_TPU")
-                              else "cpu-loopback")},
+                 "platform": "cpu-loopback"},
         "paths": {},
     }
     port = base
