@@ -52,10 +52,15 @@ static inline int64_t chrono_now_ns() {
 /* Trace timestamp source.  steady_clock::now costs ~33 ns/call on the
  * measurement host — one call per task at trace level 1 — so on x86-64
  * the hot path reads the invariant TSC (~8 ns) and converts through a
- * rate calibrated once per process against steady_clock (two ~1 ms
+ * rate calibrated once per process against steady_clock (two ~5 ms
  * windows; if they disagree > 1% — non-invariant TSC, paused VM — the
  * chrono path is kept).  Timestamps stay on the steady_clock epoch, so
- * traces mix freely with pre-calibration events. */
+ * traces mix freely with pre-calibration events.  Each end of a window
+ * is a steady_clock reading bracketed by two TSC reads, after one
+ * untimed call: the process's first steady_clock call (lazy symbol
+ * binding) runs microseconds long, and inside a 1 ms window that alone
+ * set the rate 0.3-2% slow, so traces drifted that much from every
+ * other clock. */
 int64_t ptc_now_ns() {
 #if defined(__x86_64__)
   struct Calib {
@@ -63,15 +68,31 @@ int64_t ptc_now_ns() {
     int64_t base_ns = 0;
     uint64_t base_tsc = 0;
     bool ok = false;
+    /* (TSC at a steady_clock reading, the reading): the narrowest
+     * bracket of a few tries, so a preemption mid-read is not kept */
+    static void sample(uint64_t &tsc, int64_t &ns) {
+      uint64_t width = ~0ull;
+      for (int i = 0; i < 8; i++) {
+        uint64_t a = __rdtsc();
+        int64_t n = chrono_now_ns();
+        uint64_t b = __rdtsc();
+        if (b - a < width) {
+          width = b - a;
+          tsc = a + width / 2;
+          ns = n;
+        }
+      }
+    }
     Calib() {
-      uint64_t c0 = __rdtsc();
-      int64_t n0 = chrono_now_ns();
-      while (chrono_now_ns() - n0 < 1000000) { /* spin ~1 ms */ }
-      uint64_t c1 = __rdtsc();
-      int64_t n1 = chrono_now_ns();
-      while (chrono_now_ns() - n1 < 1000000) { }
-      uint64_t c2 = __rdtsc();
-      int64_t n2 = chrono_now_ns();
+      const int64_t window_ns = 5000000;
+      uint64_t c0, c1, c2;
+      int64_t n0, n1, n2;
+      (void)chrono_now_ns();
+      sample(c0, n0);
+      while (chrono_now_ns() - n0 < window_ns) { /* spin */ }
+      sample(c1, n1);
+      while (chrono_now_ns() - n1 < window_ns) { }
+      sample(c2, n2);
       if (c1 == c0 || c2 == c1) return;
       double r1 = (double)(n1 - n0) / (double)(c1 - c0);
       double r2 = (double)(n2 - n1) / (double)(c2 - c1);
@@ -3368,6 +3389,9 @@ extern "C" {
 const char *ptc_version(void) { return "tpu-parsec-core 0.2"; }
 
 ptc_context_t *ptc_context_new(int32_t nb_workers) {
+  /* calibrate the trace clock now (~10 ms, once per process), so no
+   * timestamp the context takes later waits on it */
+  (void)ptc_now_ns();
   ptc_context *ctx = new ptc_context();
   if (nb_workers <= 0) {
     unsigned hc = std::thread::hardware_concurrency();

@@ -715,8 +715,9 @@ enum {
                              l0 = lanes in the batched call)            */
   PROF_KEY_COMM_RECV = 4, /* per-target activation delivery: instant
                            * span, aux = payload bytes                */
-  /* 6 (DEVICE_H2D) and 7 (STREAM_D2H) are emitted by the Python device
-   * layer through ptc_prof_event — keep this enum in sync with
+  /* 6 (DEVICE_H2D), 7 (STREAM_D2H) and 11-13 (the device manager's
+   * loop states: READY_WAIT, BATCH_WAIT, LOOP) are emitted by the Python
+   * device layer through ptc_prof_event — keep this enum in sync with
    * profiling/trace.py when extending */
   PROF_KEY_COLL = 8,      /* collective-step traffic on a ptc_coll_*
                            * task class: instant span at delivery

@@ -92,9 +92,12 @@ class Context:
         self.myrank, self.nodes = 0, 1
         if scheduler != "lfq":
             N.lib.ptc_context_set_scheduler(self._ptr, scheduler.encode())
+        # the trace level as last set, read by the device managers'
+        # span helper without a ctypes call (profiling.trace.ManagerSpans)
+        self._prof_level = 0
         if _mca.get("runtime.profile"):
             # same meaning as profile_enable(True): full tracing incl. EDGE
-            N.lib.ptc_profile_enable(self._ptr, 2)
+            self.profile_enable(True)
         if _mca.get("runtime.trace_ring"):
             # flight recorder: the native env read in ptc_context_new
             # covers native-only embeddings; re-applying the resolved MCA
@@ -1094,6 +1097,7 @@ class Context:
         any level (their key mask is the gate)."""
         level = 2 if enable is True else int(enable)
         N.lib.ptc_profile_enable(self._ptr, level)
+        self._prof_level = level
 
     def profile_level(self) -> int:
         """Current trace level (0 off, 1 spans, 2 +edges)."""

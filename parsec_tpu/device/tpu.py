@@ -42,6 +42,8 @@ from ..analysis.plan import PlanCheckError
 from ..core.context import Context
 from ..core.taskclass import Mem, TaskClass, TaskView
 from ..core.taskpool import Taskpool
+from ..profiling.trace import (KEY_BATCH_WAIT, KEY_DEVICE, KEY_DEVICE_LOOP,
+                               KEY_H2D, KEY_READY_WAIT, ManagerSpans)
 
 
 class _DeviceBody:
@@ -833,6 +835,8 @@ class TpuDevice:
         self.ctx = ctx
         self.device = jax_device or jax.devices()[0]
         self.qid = ctx.device_queue_new()
+        # the manager's trace events, into the .ptt ring and the profiler
+        self._spans = ManagerSpans(ctx, self.device.id, self.qid)
         self.pipeline_depth = pipeline_depth
         # max tasks fused into one vmapped dispatch (power-of-two padded)
         self.batch_max = int(os.environ.get("PTC_DEVICE_BATCH", "128"))
@@ -926,7 +930,7 @@ class TpuDevice:
         # a concurrent info()/stats_dump() copy cannot hit a
         # changed-size-during-iteration error
         self.stats = {"tasks": 0, "h2d_bytes": 0, "d2h_bytes": 0,
-                      "h2d_hits": 0, "evictions": 0, "dead_drops": 0,
+                      "h2d_hits": 0, "evictions": 0,
                       "batches": 0, "batched_tasks": 0, "d2d_bytes": 0,
                       "dp_sends": 0, "dp_d2d_bytes": 0, "dp_xfer_bytes": 0,
                       "dp_recv_bytes": 0, "invalidations": 0,
@@ -1085,7 +1089,6 @@ class TpuDevice:
             ent = self._cache.pop(handle, None)
             if ent is not None:
                 self._uncharge(ent)
-                self.stats["dead_drops"] += 1
         # the copy is dying: its affinity stamp must not route anyone
         N.lib.ptc_device_clear_data_owner(self.ctx._ptr, handle, -1)
 
@@ -1831,11 +1834,23 @@ class TpuDevice:
 
         The loop drains every ready task before dispatching, then fuses
         same-class groups into one vmapped call each — per-wave dispatch
-        cost is O(classes), not O(tasks)."""
+        cost is O(classes), not O(tasks).  Each pass is traced as three
+        spans (profiling.trace.ManagerSpans): READY_WAIT while
+        device_pop blocks on an empty queue (one span per stretch, not
+        per poll), BATCH_WAIT over the accumulate sweep, DEVICE_LOOP from
+        there until the loop pops again."""
+        sp = self._spans
         while not self._stop.is_set():
+            sp.refresh()
+            wait = sp.begin(KEY_READY_WAIT)
             task = self.ctx.device_pop(self.qid, timeout_ms=50)
-            if not task:
-                continue
+            while not task:
+                if self._stop.is_set():
+                    sp.end(wait, 0, tasks=0)
+                    return
+                if wait is None and sp.refresh():
+                    wait = sp.begin(KEY_READY_WAIT)
+                task = self.ctx.device_pop(self.qid, timeout_ms=50)
             if self._ooc and self._cache_used > \
                     self._cache_bytes * self._overcommit:
                 # out-of-core hard cap: spills ride the writeback lane,
@@ -1852,52 +1867,82 @@ class TpuDevice:
                 if not t2:
                     break
                 batch.append(t2)
+            # spans from here on follow switches flipped while waiting
+            tp = self._tp_id(task) if sp.refresh() else -1
+            sp.end(wait, len(batch), tp=tp, tasks=len(batch))
             if (len(batch) > 1 and self.batch_wait_ms > 0
                     and len(batch) < self.batch_max):
+                popped = len(batch)
+                sweep = sp.begin(KEY_BATCH_WAIT, tp)
                 deadline = time.monotonic() + self.batch_wait_ms / 1e3
                 while (len(batch) < self.batch_max
                        and time.monotonic() < deadline):
                     t2 = self.ctx.device_pop(self.qid, timeout_ms=1)
                     if t2:
                         batch.append(t2)
+                sp.end(sweep, len(batch) - popped,
+                       tasks=len(batch) - popped)
+            loop = sp.begin(KEY_DEVICE_LOOP, tp, len(batch))
+            calls = self._calls() if loop is not None else 0
             if len(batch) == 1:
                 self._dispatch(task)
-                continue
-            # group by body, preserving pop order within each group
-            groups: List[Tuple[Optional[_DeviceBody], List]] = []
-            index: Dict[int, int] = {}
-            for t in batch:
-                body = self._body_for(t)
-                key = id(body)
-                gi = index.get(key)
-                if gi is None or body is None or not body.batch:
-                    if gi is not None and body is not None \
-                            and not body.batch \
-                            and self._fuser is not None:
-                        # >= 2 ready tasks of a vmap-incompatible
-                        # class: the wave exists but cannot fuse —
-                        # recorded, mirroring certify()'s opaque-body
-                        # refusals (no silent fallback)
-                        self._fuser._refuse("unbatchable-body")
-                    index[key] = len(groups)
-                    groups.append((body, [t]))
-                else:
-                    groups[gi][1].append(t)
-            if self._fuser is not None and len(
-                    {id(b) for b, _ in groups if b is not None}) > 1:
-                # mixed ready front: each group still certifies on its
-                # own, but the front as popped was not ONE wave —
-                # recorded like certify()'s heterogeneous refusals
-                self._fuser._refuse("heterogeneous-front")
-            for body, ts in groups:
-                if body is None:
-                    for t in ts:
-                        self.ctx.task_complete(t)
-                elif len(ts) == 1 or not body.batch:
-                    for t in ts:
-                        self._dispatch_one(body, t)
-                else:
-                    self._dispatch_group(body, ts)
+            else:
+                self._dispatch_batch(batch)
+            if loop is not None:
+                calls = self._calls() - calls
+                sp.end(loop, calls, calls=calls)
+
+    def _calls(self) -> int:
+        """Device calls made so far: one per batched wave, plus one per
+        task that ran alone and was not completed from a speculative
+        epilogue's parked result."""
+        st = self.stats
+        return st["batches"] + st["tasks"] - st["batched_tasks"] \
+            - st["spec_hits"]
+
+    def _tp_id(self, task) -> int:
+        """The id of the taskpool `task` belongs to (-1: not attached)."""
+        tp = self._tp_by_ptr.get(N.lib.ptc_task_taskpool(task))
+        return tp.tp_id if tp is not None else -1
+
+    def _dispatch_batch(self, batch: List) -> None:
+        """Dispatch the tasks of one multi-task pop: grouped by body,
+        one vmapped call per group."""
+        # group by body, preserving pop order within each group
+        groups: List[Tuple[Optional[_DeviceBody], List]] = []
+        index: Dict[int, int] = {}
+        for t in batch:
+            body = self._body_for(t)
+            key = id(body)
+            gi = index.get(key)
+            if gi is None or body is None or not body.batch:
+                if gi is not None and body is not None \
+                        and not body.batch \
+                        and self._fuser is not None:
+                    # >= 2 ready tasks of a vmap-incompatible
+                    # class: the wave exists but cannot fuse —
+                    # recorded, mirroring certify()'s opaque-body
+                    # refusals (no silent fallback)
+                    self._fuser._refuse("unbatchable-body")
+                index[key] = len(groups)
+                groups.append((body, [t]))
+            else:
+                groups[gi][1].append(t)
+        if self._fuser is not None and len(
+                {id(b) for b, _ in groups if b is not None}) > 1:
+            # mixed ready front: each group still certifies on its
+            # own, but the front as popped was not ONE wave —
+            # recorded like certify()'s heterogeneous refusals
+            self._fuser._refuse("heterogeneous-front")
+        for body, ts in groups:
+            if body is None:
+                for t in ts:
+                    self.ctx.task_complete(t)
+            elif len(ts) == 1 or not body.batch:
+                for t in ts:
+                    self._dispatch_one(body, t)
+            else:
+                self._dispatch_group(body, ts)
 
     def register_dtd_task(self, task_ptr, kernel, reads, writes, shapes,
                           dtype, nb_flows):
@@ -1958,7 +2003,6 @@ class TpuDevice:
         # (h2d_stall_ns + the wave's DEVICE-span aux) and traced as a
         # dispatch-lane H2D span so the bench can pair it against
         # compute spans for the overlap fraction.
-        from ..profiling.trace import KEY_H2D
         t0 = time.perf_counter_ns()
         # ptc-scope: the dispatching task is live in hand — stamp its
         # pool's request scope into the span's (otherwise unused) class
@@ -1966,8 +2010,7 @@ class TpuDevice:
         # unscoped (prefetch-lane spans stay -1: their tasks may retire
         # while the lane stages, and overlapped h2d is not lost time).
         scope = int(N.lib.ptc_task_scope(view._ptr)) or -1
-        N.lib.ptc_prof_event(self.ctx._ptr, KEY_H2D, 0, scope,
-                             host.nbytes, self.qid, 0)
+        self._spans.event(KEY_H2D, 0, scope, host.nbytes, self.qid, 0)
         # OWNED snapshot, not the raw view: jax may read the h2d source
         # AFTER device_put returns (async dispatch), and `host` is a view
         # over native-owned memory — a wire-arrival copy dies at its last
@@ -1975,8 +2018,7 @@ class TpuDevice:
         # Observed failure: the first 16 bytes of a consumed panel turn
         # into freed-chunk heap metadata (tests/comm potrf device runs).
         darr = self._jax.device_put(np.array(host, copy=True), self.device)
-        N.lib.ptc_prof_event(self.ctx._ptr, KEY_H2D, 1, scope,
-                             host.nbytes, self.qid, 0)
+        self._spans.event(KEY_H2D, 1, scope, host.nbytes, self.qid, 0)
         stall = time.perf_counter_ns() - t0
         self._disp_stall_ns += stall
         self.stats["h2d_stall_ns"] += stall
@@ -2078,7 +2120,6 @@ class TpuDevice:
         (ptc-fuse): 0 = plain, n >= 1 = a certified wave executable
         covering n wave(s) — the bench-device fused-vs-unfused section
         counts launches straight off these spans."""
-        from ..profiling.trace import KEY_DEVICE
         cid = body.tc.id if body.tc is not None else -1
         if phase == 0:
             self._disp_stall_ns = 0
@@ -2086,8 +2127,7 @@ class TpuDevice:
         else:
             aux = self._disp_stall_ns
             self._disp_fused = 0
-        N.lib.ptc_prof_event(self.ctx._ptr, KEY_DEVICE, phase, cid,
-                             lanes, self.qid, aux)
+        self._spans.event(KEY_DEVICE, phase, cid, lanes, self.qid, aux)
 
     def _dispatch_group_chunk(self, body: _DeviceBody, tasks: List):
         fz = self._fuser

@@ -15,7 +15,10 @@ TPU-native equivalent over the native core's 8-word event stream
                  — merge applies cross-rank CLOCK SYNC, detects
                  dictionary conflicts and matches send/recv FLOW ids
                  (tracing v2); flows()/wire_latency() expose the
-                 per-message pairs
+                 per-message pairs; shift() moves it onto a profiler
+                 trace's clock (profiler_offset_ns)
+  xplane_spans   the program's `ptc.*` spans in a jax.profiler trace (the
+                 device manager's loop states, ManagerSpans)
   critpath       critical_path() / lost_time() over the executed DAG
   to_dot         executed-DAG capture from EDGE event pairs
   pins           pluggable instrumentation-module chain at the event
@@ -27,7 +30,9 @@ TPU-native equivalent over the native core's 8-word event stream
 from .trace import (KEY_EXEC, KEY_RELEASE, KEY_EDGE,
                     KEY_COMM_SEND, KEY_COMM_RECV, KEY_DEVICE, KEY_H2D,
                     KEY_STREAM, KEY_COLL, KEY_SCOPE, KEY_INFLIGHT,
-                    Dictionary, Trace, take_trace, to_dot)
+                    KEY_READY_WAIT, KEY_BATCH_WAIT, KEY_DEVICE_LOOP,
+                    Dictionary, Trace, take_trace, to_dot, xplane_spans,
+                    profiler_offset_ns)
 from .critpath import critical_path, lost_time
 from .pins import (PinsModule, PinsChain, TaskCounter, TaskProfiler,
                    CommVolume, DeviceActivity, StragglerLog, REGISTRY,
@@ -40,8 +45,9 @@ from .blackbox import Journal, FleetView
 __all__ = ["KEY_EXEC", "KEY_RELEASE", "KEY_EDGE",
            "KEY_COMM_SEND", "KEY_COMM_RECV", "KEY_DEVICE", "KEY_H2D",
            "KEY_STREAM", "KEY_COLL", "KEY_SCOPE", "KEY_INFLIGHT",
+           "KEY_READY_WAIT", "KEY_BATCH_WAIT", "KEY_DEVICE_LOOP",
            "Dictionary", "Trace",
-           "take_trace", "to_dot",
+           "take_trace", "to_dot", "xplane_spans", "profiler_offset_ns",
            "critical_path", "lost_time",
            "PinsModule", "PinsChain", "TaskCounter", "TaskProfiler",
            "CommVolume", "DeviceActivity", "StragglerLog", "REGISTRY",

@@ -65,6 +65,17 @@ KEY_INFLIGHT = 10  # crash-dump synthetic (ptc-blackbox): one instant
                    # aux = scope_id, begin = the body's open timestamp.
                    # Never emitted on the normal path; ptc_postmortem
                    # reads these to name what a dead rank was executing.
+# The device manager's loop, one span per state (ManagerSpans below), on
+# worker -1 with l1 = device queue; DEVICE_DISPATCH and DEVICE_H2D spans
+# nest inside DEVICE_LOOP.
+KEY_READY_WAIT = 11   # blocked in device_pop on an empty queue, through
+                      # the drain of what became ready: class -1, l0 0,
+                      # END aux = tasks popped
+KEY_BATCH_WAIT = 12   # the batch-accumulate sweep: class = taskpool id,
+                      # l0 0, END aux = tasks the sweep added
+KEY_DEVICE_LOOP = 13  # grouping, dispatch, write-out and completion of
+                      # the popped tasks: class = taskpool id, l0 = tasks,
+                      # END aux = device calls made
 
 _MAGIC = b"#PTCPROF"
 _VERSION = 2
@@ -82,7 +93,100 @@ _DEFAULT_KEYS = {
     KEY_COLL: ("COLL_RECV", "#00ffcc"),
     KEY_SCOPE: ("SCOPE", "#ff00aa"),
     KEY_INFLIGHT: ("INFLIGHT", "#ff4444"),
+    KEY_READY_WAIT: ("DEVICE_READY_WAIT", "#dddddd"),
+    KEY_BATCH_WAIT: ("DEVICE_BATCH_WAIT", "#ffdd00"),
+    KEY_DEVICE_LOOP: ("DEVICE_LOOP", "#7700cc"),
 }
+
+# the profiler-trace name of each device-manager state span
+DEVMGR_SPANS = {KEY_READY_WAIT: "ptc.devmgr.ready_wait",
+                KEY_BATCH_WAIT: "ptc.devmgr.batch_wait",
+                KEY_DEVICE_LOOP: "ptc.devmgr.dispatch"}
+
+
+class ManagerSpans:
+    """The one emission helper of a device manager's trace events, with
+    two sinks:
+
+    - the native ring (`ptc_prof_event`: the `.ptt` trace and PINS), on
+      while the context traces (profile level > 0) or has PINS modules;
+    - the profiler's trace, on while a `jax.profiler` session records: a
+      TraceAnnotation named `ptc.devmgr.<state>` on the manager's thread,
+      on the clock of the device ops, with args `chip` (JAX device id),
+      `qid`, `tp` (taskpool id of the first popped task: one solve),
+      `tasks` and, on `dispatch`, `calls` and `ptc_ns` (the native clock
+      at its begin, which `profiler_offset_ns` pairs with the span's
+      start).
+
+    `refresh()` reads both switches; a span or event tests the cached
+    flags only, so with both sinks off it makes no ctypes call and no
+    TraceAnnotation.  A span keeps the sinks it began in."""
+
+    __slots__ = ("_ctx", "_lib", "_traceme", "chip", "qid", "ring",
+                 "xprof")
+
+    def __init__(self, ctx, chip: int, qid: int):
+        from jax.profiler import TraceAnnotation
+
+        from .. import _native as N
+        self._ctx = ctx
+        self._lib = N.lib
+        self._traceme = TraceAnnotation
+        self.chip, self.qid = int(chip), int(qid)
+        self.ring = self.xprof = False
+
+    def refresh(self) -> bool:
+        """Re-read both switches; True when either sink is on."""
+        ctx = self._ctx
+        self.ring = ctx._prof_level > 0 or ctx._pins_chain is not None
+        self.xprof = self._traceme.is_enabled()
+        return self.ring or self.xprof
+
+    def event(self, key: int, phase: int, cid: int, l0: int, l1: int,
+              aux: int) -> None:
+        """A native-ring event only (DEVICE_DISPATCH, DEVICE_H2D)."""
+        if self.ring:
+            self._lib.ptc_prof_event(self._ctx._ptr, key, phase, cid, l0,
+                                     l1, aux)
+
+    def begin(self, key: int, tp: int = -1, tasks: int = 0):
+        """Open a state span with ring words (class = tp, l0 = tasks,
+        l1 = qid) at both ends; the profiler span gets `tp` and `tasks`
+        now where known (tp >= 0, tasks > 0), else from end().  None
+        when both sinks are off."""
+        if not (self.ring or self.xprof):
+            return None
+        tm = None
+        if self.xprof:
+            args = {"chip": self.chip, "qid": self.qid}
+            if tp >= 0:
+                args["tp"] = tp
+            if tasks > 0:
+                args["tasks"] = tasks
+            tm = self._traceme(DEVMGR_SPANS[key], **args)
+            tm.__enter__()
+            if key == KEY_DEVICE_LOOP:
+                # read after the span's start and before the ring's
+                # begin event, so the ring begin maps inside the span
+                tm.set_metadata(ptc_ns=self._lib.ptc_clock_ns())
+        if self.ring:
+            self._lib.ptc_prof_event(self._ctx._ptr, key, 0, tp, tasks,
+                                     self.qid, 0)
+        return (key, tp, tasks, tm, self.ring)
+
+    def end(self, span, aux: int = 0, **args) -> None:
+        """Close `span` (None: nothing): the ring END event carries
+        `aux`, the profiler span gains `args`."""
+        if span is None:
+            return
+        key, tp, tasks, tm, ring = span
+        if ring:
+            self._lib.ptc_prof_event(self._ctx._ptr, key, 1, tp, tasks,
+                                     self.qid, aux)
+        if tm is not None:
+            if args:
+                tm.set_metadata(**args)
+            tm.__exit__(None, None, None)
 
 
 class Dictionary:
@@ -568,6 +672,15 @@ class Trace:
                 json.dump(doc, f)
         return doc
 
+    def shift(self, offset_ns: int) -> "Trace":
+        """Move every timestamp by `offset_ns` (from profiler_offset_ns:
+        onto a profiler trace's clock, so to_perfetto() output lines up
+        with the device ops); in place, returns self."""
+        self.events[:, 7] += int(offset_ns)
+        self.meta["profiler_offset_ns"] = \
+            int(self.meta.get("profiler_offset_ns", 0)) + int(offset_ns)
+        return self
+
     def counts(self) -> Dict[str, int]:
         """Event counts per key name — the cheap oracle used by trace
         assertions (reference: tests/profiling/check-comms.py)."""
@@ -612,6 +725,65 @@ def take_trace(ctx, rank: Optional[int] = None,
         pass
     return Trace(ctx.profile_take(), rank=rank, class_names=class_names,
                  meta=m)
+
+
+def traceme_args(name: str):
+    """(base name, args) of a profiler event name that carries its args
+    encoded as `name#k=v,...#` (numbers as int where they parse)."""
+    base, sep, rest = name.partition("#")
+    args = {}
+    if sep:
+        for kv in rest.rstrip("#").split(","):
+            k, _, v = kv.partition("=")
+            if k:
+                try:
+                    args[k] = int(v)
+                except ValueError:
+                    args[k] = v
+    return base, args
+
+
+def xplane_spans(path: str, prefix: str = "ptc.",
+                 threads: bool = False) -> List[tuple]:
+    """The host spans of a `jax.profiler` trace file (`.xplane.pb`) whose
+    name starts with `prefix` (with `threads`, every span of a thread
+    that has one), sorted by start:
+    (name, start_ns, end_ns, line, args), `line` the index of the span's
+    thread in the host plane, `args` its args (the event's stats, or the
+    `name#k=v,...#` form)."""
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for li, line in enumerate(plane.lines):
+            mine, hit = [], False
+            for ev in line.events:
+                name, args = traceme_args(ev.name)
+                match = name.startswith(prefix)
+                hit = hit or match
+                if match or threads:
+                    args.update((k, v) for k, v in ev.stats)
+                    mine.append((name, int(ev.start_ns), int(ev.end_ns),
+                                 li, args))
+            if hit:
+                out += mine
+    out.sort(key=lambda s: s[1])
+    return out
+
+
+def profiler_offset_ns(pairs) -> tuple:
+    """The offset from the native trace clock to a profiler trace's
+    clock, from (ptc_ns, profiler start_ns) pairs: the `ptc_ns` arg and
+    start of each `ptc.devmgr.dispatch` span.  Returns (median offset,
+    spread), the spread being the interquartile range of the pairs'
+    offsets; Trace.shift(median) puts a `.ptt` on the profiler's
+    clock."""
+    d = np.asarray([int(p) - int(n) for n, p in pairs], dtype=np.int64)
+    if not len(d):
+        raise ValueError("no (ptc_ns, start) pairs")
+    q1, med, q3 = np.percentile(d, [25, 50, 75])
+    return int(round(med)), int(round(q3 - q1))
 
 
 def _node_id(cid, l0, l1, cname):
