@@ -236,6 +236,7 @@ ptc_context::~ptc_context() {
   for (auto *c : worker_executed) delete c;
   for (auto *c : worker_cpu) delete c;
   for (auto *c : worker_bypass) delete c;
+  for (auto *s : flight) delete s;
   delete sched;
   ptc_task *t = free_list;
   while (t) {
@@ -1987,6 +1988,11 @@ void ptc_schedule_task(ptc_context *ctx, int worker, ptc_task *t) {
   /* comm-thread deliveries can precede/overlap the lazy start */
   if (!ctx->started.load(std::memory_order_acquire))
     ptc_context_start(ctx);
+  int slot = mag_slot(ctx);
+  if (slot >= 0)
+    tick1(ctx->flight[(size_t)slot]->scheduled);
+  else
+    ctx->flight_ext.scheduled.fetch_add(1, std::memory_order_relaxed);
   if (tl_bypass && ctx->sched_bypass.load(std::memory_order_relaxed) &&
       !(t->tp && t->tp->qos.load(std::memory_order_relaxed))) {
     /* QoS pools never ride the thread-local bypass: every ready
@@ -3017,6 +3023,7 @@ static void worker_main(ptc_context *ctx, int worker) {
   tl_mag_worker = worker;
   std::atomic<int64_t> *bypass_ctr = ctx->worker_bypass[(size_t)worker];
   std::atomic<int64_t> *exec_ctr = ctx->worker_executed[(size_t)worker];
+  std::atomic<int64_t> *done_ctr = &ctx->flight[(size_t)worker]->done;
   int misses = 0;
   tl_bypass = true;
   while (!ctx->shutdown.load(std::memory_order_acquire)) {
@@ -3031,6 +3038,11 @@ static void worker_main(ptc_context *ctx, int worker) {
       misses = 0;
       tick1(*exec_ctr); /* single writer: this worker */
       execute_task(ctx, worker, t);
+      /* after the push to a device queue and after complete_task
+       * scheduled the successors: a reader that sees 0 and then finds
+       * a device queue empty has missed nothing this task routed */
+      done_ctr->store(done_ctr->load(std::memory_order_relaxed) + 1,
+                      std::memory_order_release);
       continue;
     }
     if (++misses < 64) {
@@ -3409,6 +3421,7 @@ ptc_context_t *ptc_context_new(int32_t nb_workers) {
     ctx->worker_executed.push_back(new std::atomic<int64_t>(0));
     ctx->worker_cpu.push_back(new std::atomic<int32_t>(-1));
     ctx->worker_bypass.push_back(new std::atomic<int64_t>(0));
+    ctx->flight.push_back(new ptc_context::FlightShard());
     ctx->task_mags.push_back(new ptc_context::TaskMag());
   }
   /* always-on metrics: one histogram set per worker + the shared
@@ -3710,6 +3723,20 @@ void ptc_context_set_qos_preempt(ptc_context_t *ctx, int32_t on) {
 
 int32_t ptc_context_get_qos_preempt(ptc_context_t *ctx) {
   return ctx->qos_preempt.load(std::memory_order_relaxed) ? 1 : 0;
+}
+
+/* Every done count first, then every scheduled count: a task read done
+ * has its own and its successors' scheduled counts visible (release /
+ * acquire), and the counts only grow, so the difference is never below
+ * the number in flight between the two passes, and never negative. */
+int64_t ptc_tasks_in_flight(ptc_context_t *ctx) {
+  int64_t done = 0, scheduled = 0;
+  for (auto *s : ctx->flight)
+    done += s->done.load(std::memory_order_acquire);
+  for (auto *s : ctx->flight)
+    scheduled += s->scheduled.load(std::memory_order_acquire);
+  scheduled += ctx->flight_ext.scheduled.load(std::memory_order_acquire);
+  return scheduled - done;
 }
 
 /* Dispatch fast-path counters (Context.sched_stats()).  Layout:

@@ -136,6 +136,11 @@ const char *ptc_context_get_scheduler(ptc_context_t *ctx);
  * of round-tripping schedule()+select().  Default on. */
 void ptc_context_set_sched_bypass(ptc_context_t *ctx, int32_t on);
 int32_t ptc_context_get_sched_bypass(ptc_context_t *ctx);
+/* tasks handed to the scheduler and not yet back from their execution
+ * (routed to a device queue, completed, requeued or failed).  0 means no
+ * task can still land on a device queue except through a completion
+ * made outside the workers (device managers, writeback, comm). */
+int64_t ptc_tasks_in_flight(ptc_context_t *ctx);
 /* dispatch fast-path counters — [0] bypass hits, [1] bypass enabled,
  * [2]/[3] task-freelist hits/misses, [4]/[5] arena hits/misses,
  * [6]/[7] DTD insert batches / batch-inserted tasks, [8]/[9] scheduler

@@ -1044,6 +1044,20 @@ struct ptc_context {
    * kept here too so pre-start sets survive.  Default on. */
   std::atomic<bool> qos_preempt{true};
   std::vector<std::atomic<int64_t> *> worker_bypass;
+  /* tasks handed to the scheduler (ptc_schedule_task, bypass slot
+   * included) and not yet back from execute_task, whatever its outcome
+   * (ptc_tasks_in_flight), as two monotonic counts.  A worker counts
+   * what it schedules and what it finishes in its own line, single
+   * writer, so the hot path shares no line between workers; main, comm
+   * and device-manager threads schedule through flight_ext.  A task's
+   * successors are scheduled inside its complete_task, before its
+   * worker counts it done: the difference reads 0 only with no release
+   * chain alive. */
+  struct alignas(64) FlightShard {
+    std::atomic<int64_t> scheduled{0}, done{0};
+  };
+  std::vector<FlightShard *> flight; /* one per worker */
+  FlightShard flight_ext;            /* .scheduled only */
 
   /* batched DTD insertion accounting (ptc_dtask_insert_batch) */
   std::atomic<int64_t> insert_batches{0};

@@ -169,6 +169,7 @@ _sigs = {
     "ptc_context_set_scheduler": (C.c_int32, [C.c_void_p, C.c_char_p]),
     "ptc_context_set_sched_bypass": (None, [C.c_void_p, C.c_int32]),
     "ptc_context_get_sched_bypass": (C.c_int32, [C.c_void_p]),
+    "ptc_tasks_in_flight": (C.c_int64, [C.c_void_p]),
     "ptc_sched_stats": (C.c_int64, [C.c_void_p, C.POINTER(C.c_int64),
                                     C.c_int64]),
     "ptc_tp_set_qos": (None, [C.c_void_p, C.c_int32, C.c_int64]),

@@ -385,6 +385,13 @@ class Context:
         n = N.lib.ptc_worker_steals(self._ptr, buf, cap)
         return [buf[i] for i in range(n)]
 
+    def tasks_in_flight(self) -> int:
+        """Tasks handed to the scheduler and not yet back from their
+        execution (routed to a device queue, completed, requeued or
+        failed).  A task's successors are counted before it leaves, so
+        0 means the workers hold no release chain."""
+        return N.lib.ptc_tasks_in_flight(self._ptr)
+
     def sched_stats(self) -> dict:
         """Dispatch fast-path counters: same-worker bypass hits (tasks
         that skipped the schedule/select round trip), task/arena
@@ -1045,8 +1052,10 @@ class Context:
         spill traffic, dispatch-time h2d stall, and the counter-level
         overlap ratio — the fraction of h2d nanoseconds spent on the
         prefetch lane (overlapping compute) rather than stalling a
-        dispatch.  Per-device info objects ride along under
-        "devices"."""
+        dispatch; and the accumulate sweep's counts (`sweeps`,
+        `sweep_hits` that added a task, `sweep_quiesced` closed by the
+        engine's empty in-flight count, `sweep_ns`).  Per-device info
+        objects ride along under "devices"."""
         devs = [dev.info() for dev in self._devices]
         keys = ("prefetch_staged", "prefetch_bytes", "prefetch_hits",
                 "prefetch_misses", "prefetch_wasted", "reserve_fails",
@@ -1054,7 +1063,8 @@ class Context:
                 "prefetch_h2d_ns", "ooc_waits", "h2d_hits", "h2d_bytes",
                 "evictions", "stream_serves", "stream_slices",
                 "stream_d2h_ns", "stream_bytes", "prefetch_wakeups",
-                "cache_peak_bytes")
+                "cache_peak_bytes", "sweeps", "sweep_hits",
+                "sweep_quiesced", "sweep_ns")
         agg = {k: sum(d["stats"].get(k, 0) for d in devs) for k in keys}
         moved = agg["prefetch_h2d_ns"] + agg["h2d_stall_ns"]
         agg["overlap_ratio"] = (

@@ -169,7 +169,7 @@ def spotrf_device(ctx, coll, jax_device=None) -> TpuDevice:
     """The TpuDevice a spotrf run uses: memory budget from the device's
     bytes_limit (spotrf_budget), wide batches (whole waves in ONE stack,
     so consumers hit the single-take gather path), waves accumulated for
-    5 ms."""
+    up to 5 ms while the engine still routes tasks."""
     import jax
     jax_device = jax_device or jax.devices()[0]
     local = len(local_tile_index(coll)) * coll.mb * coll.nb \

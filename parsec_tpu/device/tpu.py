@@ -840,12 +840,13 @@ class TpuDevice:
         self.pipeline_depth = pipeline_depth
         # max tasks fused into one vmapped dispatch (power-of-two padded)
         self.batch_max = int(os.environ.get("PTC_DEVICE_BATCH", "128"))
-        # opt-in accumulate window: after a MULTI-task drain, keep
-        # sweeping for up to this long so a wave being released
-        # concurrently by workers lands in ONE dispatch — worth paying
-        # when per-dispatch cost dominates (bench sets it for spotrf;
-        # 0 = off, and single-task pops never wait, so latency-bound
-        # chains are unaffected)
+        # opt-in accumulate sweep: after a MULTI-task drain, keep
+        # sweeping while the engine still holds a task that could land
+        # on this queue (ctx.tasks_in_flight() > 0), for up to this
+        # long, so a wave being released concurrently by workers lands
+        # in ONE dispatch — worth it when per-dispatch cost dominates
+        # (spotrf_device sets it; 0 = off, and single-task pops never
+        # sweep, so latency-bound chains are unaffected)
         self.batch_wait_ms = float(
             os.environ.get("PTC_DEVICE_BATCH_WAIT_MS", "0"))
         # byte cap on one vmapped call's stacked operands (see
@@ -945,6 +946,11 @@ class TpuDevice:
                       # waves demoted to per-task dispatch because their
                       # kernel has no batching rule (trace-time only)
                       "batch_fallbacks": 0,
+                      # accumulate sweeps (batch_wait_ms): those that
+                      # added a task, those the engine's empty in-flight
+                      # count closed before the deadline, their time
+                      "sweeps": 0, "sweep_hits": 0, "sweep_quiesced": 0,
+                      "sweep_ns": 0,
                       "h2d_stall_ns": 0, "prefetch_h2d_ns": 0,
                       "ooc_waits": 0,
                       # cross-rank streaming (progressive serve + event-
@@ -1874,14 +1880,15 @@ class TpuDevice:
                     and len(batch) < self.batch_max):
                 popped = len(batch)
                 sweep = sp.begin(KEY_BATCH_WAIT, tp)
-                deadline = time.monotonic() + self.batch_wait_ms / 1e3
-                while (len(batch) < self.batch_max
-                       and time.monotonic() < deadline):
-                    t2 = self.ctx.device_pop(self.qid, timeout_ms=1)
-                    if t2:
-                        batch.append(t2)
+                t0 = time.perf_counter_ns()
+                closed = self._sweep(batch)
+                st = self.stats
+                st["sweeps"] += 1
+                st["sweep_hits"] += len(batch) > popped
+                st["sweep_quiesced"] += closed == "quiesced"
+                st["sweep_ns"] += time.perf_counter_ns() - t0
                 sp.end(sweep, len(batch) - popped,
-                       tasks=len(batch) - popped)
+                       tasks=len(batch) - popped, closed=closed)
             loop = sp.begin(KEY_DEVICE_LOOP, tp, len(batch))
             calls = self._calls() if loop is not None else 0
             if len(batch) == 1:
@@ -1891,6 +1898,32 @@ class TpuDevice:
             if loop is not None:
                 calls = self._calls() - calls
                 sp.end(loop, calls, calls=calls)
+
+    def _sweep(self, batch: List) -> str:
+        """The accumulate sweep: pop more tasks into `batch` while the
+        engine still holds one that could land here, until the
+        batch_wait_ms deadline ("deadline") or batch_max ("full").
+        "quiesced": the in-flight count read 0 before a pop that found
+        the queue empty.  A worker pushes a routed task before it counts
+        it done, so reading first and popping second misses nothing.
+        Releases that arrive over the wire from other ranks never pass
+        through this process's count: with the comm engine up, only the
+        deadline and batch_max close the sweep."""
+        ctx, qid = self.ctx, self.qid
+        local = not ctx.comm_enabled
+        deadline = time.monotonic() + self.batch_wait_ms / 1e3
+        while len(batch) < self.batch_max:
+            if time.monotonic() >= deadline:
+                return "deadline"
+            quiet = local and ctx.tasks_in_flight() == 0
+            t2 = ctx.device_pop(qid, timeout_ms=0)
+            if not t2:
+                if quiet:
+                    return "quiesced"
+                t2 = ctx.device_pop(qid, timeout_ms=1)
+            if t2:
+                batch.append(t2)
+        return "full"
 
     def _calls(self) -> int:
         """Device calls made so far: one per batched wave, plus one per

@@ -2209,6 +2209,9 @@ def gemm_dist_wave_fuse(rank: int, nodes: int, port: int, N: int = 64,
                 # per-device snapshot: ctx.device_stats() would fold
                 # the previous pass's (stopped) device back in
                 st = dev.info()["fuse"]
+                # remote releases are invisible to the engine's
+                # in-flight count: no sweep may close on it here
+                assert dev.stats["sweep_quiesced"] == 0, dev.stats
                 dev.stop()
                 tiles = {}
                 nt = C.mt

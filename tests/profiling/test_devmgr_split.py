@@ -130,6 +130,19 @@ def test_clock_reads_offset_spread_and_drift():
     assert S.clock([]) == {}
 
 
+def test_sweep_close_shares_from_the_closed_arg():
+    spans = [span("ptc.devmgr.batch_wait", 0, 1, closed="quiesced"),
+             span("ptc.devmgr.batch_wait", 2, 3, closed="quiesced"),
+             span("ptc.devmgr.batch_wait", 4, 5, closed="deadline"),
+             span("ptc.devmgr.batch_wait", 6, 7, closed="full"),
+             # a trace of a manager that does not say how it closed
+             span("ptc.devmgr.batch_wait", 8, 9),
+             span("ptc.devmgr.dispatch", 9, 10, closed="full")]
+    assert S.sweep_close(spans) == {"quiesced": 50.0, "deadline": 25.0,
+                                    "full": 25.0, "sweeps": 4}
+    assert S.sweep_close(spans[4:]) == {}
+
+
 def test_recorded_trace_without_manager_spans_is_all_rest():
     tr = T.Trace.from_file(FIXTURE, 1)
     spans = S.xplane_spans(FIXTURE, "ptc.devmgr.", threads=True)
@@ -170,6 +183,11 @@ def test_traced_benchmark_run_on_the_cpu(tmp_path, monkeypatch):
         result["metrics"]["devmgr.calls_per_solve"]["value"])
     assert len({a["tp"] for a in disp}) == result["attempted"]
     assert rep["clock"]["pairs"] == len(disp)
+    # every sweep says how it closed
+    sweeps = rep["spans"]["ptc.devmgr.batch_wait"]
+    assert rep["sweep_close"]["sweeps"] == sweeps
+    assert sum(rep["sweep_close"][k] for k in S.CLOSES) == \
+        pytest.approx(100.0)
     # the CPU trace has no device plane: all of the solves is idle
     assert sum(rep["split"][k] for k in SHARES) == pytest.approx(100.0)
     assert rep["split"]["device.idle_share"] == pytest.approx(100.0)

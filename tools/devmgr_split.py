@@ -27,8 +27,9 @@ The last line of standard output is one JSON object: the split, the
 clock offset from the native trace clock to the profiler's
 (`profiler_offset_ns` over the dispatch spans: median, spread, and the
 drift between the first and last tenth of the spans), the span counts,
-and the host spans on the managers' threads that cover the most idle
-time.  `--dump <file.json.gz>` also writes the reduced trace: busy
+how the batch_wait sweeps closed (`sweep_close`: % by the engine's
+empty in-flight count, by the deadline, by batch_max), and the host
+spans on the managers' threads that cover the most idle time.  `--dump <file.json.gz>` also writes the reduced trace: busy
 intervals, solves and the managers' threads' spans.
 """
 from __future__ import annotations
@@ -51,6 +52,10 @@ from parsec_tpu.profiling.trace import (profiler_offset_ns,  # noqa: E402
 # tracing to a jaxpr, lowering, and compiling or loading from the cache
 BUILD_SPANS = ("trace_to_jaxpr_dynamic", "lower_sharding_computation",
                "backend_compile_and_load")
+# how a `ptc.devmgr.batch_wait` sweep closed, its `closed` arg: the
+# engine had nothing left in flight, the batch_wait_ms deadline passed,
+# or the batch reached batch_max
+CLOSES = ("quiesced", "deadline", "full")
 STATES = (("devmgr.build_idle", None),
           ("devmgr.batch_wait_idle", "ptc.devmgr.batch_wait"),
           ("devmgr.dispatch_idle", "ptc.devmgr.dispatch"),
@@ -115,6 +120,18 @@ def clock(spans) -> dict:
             "over_s": (pairs[-1][1] - pairs[0][1]) / 1e9}
 
 
+def sweep_close(spans) -> dict:
+    """The share (%) of the sweeps that carry a `closed` arg closed each
+    way (CLOSES), and their count `sweeps`; {} when none carries it."""
+    closed = [a["closed"] for n, _, _, _, a in spans
+              if n == "ptc.devmgr.batch_wait" and "closed" in a]
+    if not closed:
+        return {}
+    out = {k: 100.0 * closed.count(k) / len(closed) for k in CLOSES}
+    out["sweeps"] = len(closed)
+    return out
+
+
 def top_idle_spans(tr: T.Trace, spans, n: int = 25) -> list:
     """The names of the spans on the managers' threads whose union
     covers the most idle time inside the solves: [name, seconds]."""
@@ -139,7 +156,8 @@ def report(tr: T.Trace, spans) -> dict:
         if name.startswith("ptc.devmgr."):
             counts[name] = counts.get(name, 0) + 1
     return {"split": split(tr, spans), "clock": clock(spans),
-            "spans": counts, "top_idle_spans": top_idle_spans(tr, spans)}
+            "spans": counts, "sweep_close": sweep_close(spans),
+            "top_idle_spans": top_idle_spans(tr, spans)}
 
 
 def dump(path: str, tr: T.Trace, spans) -> None:
