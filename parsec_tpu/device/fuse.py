@@ -77,7 +77,7 @@ def _get_chained(jax_mod, kernel0, sig0: tuple, levels_struct: tuple):
     """One jitted program running the producer wave's (vmapped) kernel
     followed by each chained level's kernel, with level-l inputs
     gathered from level-(l-1)'s in-program outputs ("chain" specs) or
-    marshaled like any wave flow ("idx"/"stacked"/"bcast").  Returns
+    marshaled like any wave flow ("idx"/"bcast").  Returns
     (callable, compiled_now)."""
     key = (kernel0, sig0, levels_struct)
     f = _CHAIN_CACHE.get(key)
@@ -107,10 +107,6 @@ def _get_chained(jax_mod, kernel0, sig0: tuple, levels_struct: tuple):
                     lins.append(jnp.take(args[ai], args[ai + 1],
                                          axis=0))
                     ai += 2
-                    axes.append(0)
-                elif k == "stacked":
-                    lins.append(args[ai])
-                    ai += 1
                     axes.append(0)
                 else:  # bcast
                     lins.append(args[ai])
@@ -541,9 +537,7 @@ class WaveFuser:
         code — an error there fails the tasks loudly (re-dispatching
         already-written lanes would double-write)."""
         dev = self.dev
-        from .tpu import (_StackRef, _bucket, _single_stack,
-                          grouped_stack)
-        jnp = dev._jax.numpy
+        from .tpu import _StackRef, _bucket
         tp_id = id(body.tp)
         try:
             bucket0 = _bucket(len(tasks))
@@ -593,19 +587,11 @@ class WaveFuser:
                                 specs.append(("bcast",))
                                 extra_args.append(first)
                         else:
-                            one = _single_stack(ents)
-                            if one is not None:
-                                stack, idxs = one
-                                idxs += [idxs[0]] * (bucket_l
-                                                     - len(idxs))
-                                specs.append(("idx",))
-                                extra_args += [
-                                    stack,
-                                    np.asarray(idxs, dtype=np.int32)]
-                            else:
-                                specs.append(("stacked",))
-                                extra_args.append(grouped_stack(
-                                    jnp, ents, bucket_l))
+                            stack, idxs = dev._fold(ents)
+                            idxs += [idxs[0]] * (bucket_l - len(idxs))
+                            specs.append(("idx",))
+                            extra_args += [
+                                stack, np.asarray(idxs, dtype=np.int32)]
                     else:
                         self._refuse("chain:unresolvable-inputs")
                         return False

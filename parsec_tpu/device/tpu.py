@@ -30,6 +30,7 @@ Manual flush() remains for bulk host reads (to_dense etc.).
 """
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import time
@@ -89,8 +90,11 @@ class _DeviceBody:
 _JIT_CACHE: Dict[object, Callable] = {}
 
 # batched variants: kernel fn -> jit(vmap(kernel)).  One executable per
-# (kernel, bucket size, tile shape/dtype); bucket padding (powers of two)
-# keeps the number of compiles logarithmic in the max batch.
+# (kernel, bucket size, tile shape/dtype) and, for the fused forms, per
+# shape of each source stack a lane gathers from.  Lane buckets and
+# batch stacks are powers of two, and a flow whose lanes come from
+# several stacks is folded into one first (fold_stack), so the programs
+# a DAG can build do not depend on where its waves were cut.
 _VMAP_CACHE: Dict[object, Callable] = {}
 _FUSED_CACHE: Dict[object, Callable] = {}
 
@@ -675,8 +679,8 @@ class _Epilogue:
 def _single_stack(ents):
     """(stack, row_idxs) when every entry is a lazy slice of ONE source
     stack — the gather can then ride inside the fused program — else
-    None.  Shared by grouped_stack's eager fast path and the fused
-    dispatcher so padding/identity semantics cannot diverge."""
+    None.  Shared by fold_stack and the fused dispatcher so
+    padding/identity semantics cannot diverge."""
     if not ents or not all(isinstance(e, _StackRef) for e in ents):
         return None
     if len({id(e.stack) for e in ents}) != 1:
@@ -689,9 +693,11 @@ _FLUSH_CHUNK_BYTES = 256 << 20
 
 
 def _bucket(n: int) -> int:
-    """Round a batch size up to a power of two: stacked shapes then come
-    from a log-bounded set, so XLA compiles each batched kernel O(log B)
-    times instead of once per distinct wave width."""
+    """Round a batch size up to a power of two: the lanes of a batched
+    call and the rows of the stack it leaves come from a log-bounded
+    set.  A program that gathers from a stack is keyed by the stack's
+    rows too; grouped_stack and fold_stack keep the gathers of mixed
+    waves to such shapes."""
     b = 1
     while b < n:
         b <<= 1
@@ -727,47 +733,104 @@ def local_tile_index(coll):
     return out
 
 
-def grouped_stack(jnp, ents, bucket=None):
+@functools.lru_cache(maxsize=None)
+def _rows_into(jax_mod):
+    """jit(out, stack, rows, dst, n) -> out with out[dst[i]] =
+    stack[rows[i]] for i < n, one row at a time in place (`out` is
+    donated).  `rows` and `dst` are as long as `out`, whatever n is, so
+    there is one program per pair of shapes of `out` and `stack`."""
+    lax = jax_mod.lax
+
+    def rows_into(out, stack, rows, dst, n):
+        def put(i, o):
+            row = lax.dynamic_index_in_dim(stack, rows[i])
+            return lax.dynamic_update_index_in_dim(o, row, dst[i], 0)
+        return lax.fori_loop(0, n, put, out)
+    return jax_mod.jit(rows_into, donate_argnums=0)
+
+
+def grouped_stack(jnp, ents, bucket=None, seen=None):
     """One stacked (bucket, *tile) device array from per-tile entries
-    (concrete arrays or _StackRefs), in O(source stacks) device ops
-    instead of O(tiles) slice ops.  Rows past len(ents) are padding (row 0
-    repeated).  Shared by the batched dispatch gather and flush()."""
-    bucket = bucket or len(ents)
+    (concrete arrays or _StackRefs): fold_stack, then one take.  Rows
+    past len(ents) are padding (entry 0 repeated).  Used by flush()."""
+    stack, rows = fold_stack(jnp, ents, seen)
+    rows += [rows[0]] * ((bucket or len(ents)) - len(rows))
+    return jnp.take(stack, np.asarray(rows, dtype=np.int32), axis=0)
+
+
+def fold_stack(jnp, ents, seen=None):
+    """(stack, rows): one stack that holds every entry (concrete arrays
+    or _StackRefs), and the row of each, so a wave gathers a flow from
+    one stack inside its program, in O(source stacks) device ops.
+
+    Entries of one stack need nothing, and loose tiles are stacked,
+    padded to a power of two rows.  Otherwise the largest source stack
+    is copied and every other entry written into a row of the copy that
+    no entry names, the stacked loose tiles being one more source; where
+    it has too few such rows, or more than twice the lane bucket, a
+    zeroed stack of the lane bucket's rows takes the entries in order.
+    So the stack built holds at most twice the rows of the lane bucket,
+    each written once, from the row it copies, and every program this
+    runs is keyed by the shapes of two stacks, never by how many rows a
+    source gives: the wave then runs the program it runs when one stack
+    holds the whole wave.  `seen`, a set, collects each program's
+    key."""
     one = _single_stack(ents)
     if one is not None:
-        stack, idxs = one
-        idxs += [idxs[0]] * (bucket - len(idxs))
-        return jnp.take(stack, jnp.asarray(idxs, dtype=jnp.int32),
-                        axis=0)
-    stacks = {id(e.stack) for e in ents if isinstance(e, _StackRef)}
-    if stacks and len(ents) > len(stacks) + 2:
-        by_stack = {}   # id -> (stack, [(orig_pos, row_idx)])
-        loose = []      # [(orig_pos, array)]
-        for pos, e in enumerate(ents):
-            if isinstance(e, _StackRef):
-                by_stack.setdefault(id(e.stack), (e.stack, []))[1] \
-                    .append((pos, e.idx))
-            else:
-                loose.append((pos, e))
-        parts, order = [], []
-        for stack, rows in by_stack.values():
-            parts.append(jnp.take(
-                stack, jnp.asarray([r for _, r in rows],
-                                   dtype=jnp.int32), axis=0))
-            order.extend(p for p, _ in rows)
-        if loose:
-            parts.append(jnp.stack([a for _, a in loose]))
-            order.extend(p for p, _ in loose)
-        cat = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-        perm = [0] * len(ents)
-        for cat_row, orig_pos in enumerate(order):
-            perm[orig_pos] = cat_row
-        perm += [perm[0]] * (bucket - len(perm))
-        return jnp.take(cat, jnp.asarray(perm, dtype=jnp.int32), axis=0)
-    mats = [e.materialize() if isinstance(e, _StackRef) else e
-            for e in ents]
-    mats += [mats[0]] * (bucket - len(mats))
-    return jnp.stack(mats)
+        return one
+    by_stack = {}   # id -> (stack, [(row_idx, pos)])
+    loose = []      # [(pos, array)]
+    for pos, e in enumerate(ents):
+        if isinstance(e, _StackRef):
+            by_stack.setdefault(id(e.stack), (e.stack, []))[1] \
+                .append((e.idx, pos))
+        else:
+            loose.append((pos, e))
+    sources = list(by_stack.values())
+    if loose:
+        mats = [a for _, a in loose]
+        tiles = jnp.stack(mats + [mats[0]] * (_bucket(len(mats))
+                                              - len(mats)))
+        if seen is not None:
+            seen.add(("stack", tiles.shape, str(tiles.dtype)))
+        if not sources:
+            return tiles, list(range(len(ents)))
+        sources.append((tiles, [(i, p) for i, (p, _) in
+                                enumerate(loose)]))
+    base, named = max(sources, key=lambda s: (s[0].shape[0], len(s[1])))
+    used = {r for r, _ in named}
+    free = [r for r in range(base.shape[0]) if r not in used]
+    rows = [0] * len(ents)
+    lanes = _bucket(len(ents))
+    if base.shape[0] <= 2 * lanes and \
+            sum(len(p) for b, p in sources if b is not base) <= len(free):
+        out = jnp.copy(base)
+        key = ("copy", out.shape, str(out.dtype))
+        for r, pos in named:
+            rows[pos] = r
+        sources = [s for s in sources if s[0] is not base]
+    else:
+        # on the device of the stacks, so `out` is committed alike
+        out = jnp.zeros((lanes,) + base.shape[1:], base.dtype,
+                        device=base.sharding)
+        key = ("zeros", out.shape, str(out.dtype))
+        free = list(range(len(ents)))
+    if seen is not None:
+        seen.add(key)
+    import jax
+    into = _rows_into(jax)
+    for stack, pairs in sources:
+        put, free = free[:len(pairs)], free[len(pairs):]
+        src = np.zeros(out.shape[0], np.int32)
+        dst = np.zeros(out.shape[0], np.int32)
+        src[:len(pairs)] = [r for r, _ in pairs]
+        dst[:len(pairs)] = put
+        out = into(out, stack, src, dst, np.int32(len(pairs)))
+        if seen is not None:
+            seen.add(("into", out.shape, stack.shape, str(stack.dtype)))
+        for (_, pos), d in zip(pairs, put):
+            rows[pos] = d
+    return out, rows
 
 
 def _conc(ent: "_CacheEnt"):
@@ -913,6 +976,8 @@ class TpuDevice:
         # residency pressure — out-of-core pools keep the PR 12
         # spill behavior instead of pinning unaccounted stacks
         self._chain_pinned = 0
+        # keys of the eager-gather programs built (stats gather_shapes)
+        self._gather_keys: set = set()
         # ptc-fuse wave compiler (device.wave_fuse knob; None = off
         # reproduces the per-group batched dispatch path bit-exactly)
         self._fuser = None
@@ -935,7 +1000,8 @@ class TpuDevice:
                       "batches": 0, "batched_tasks": 0, "d2d_bytes": 0,
                       "dp_sends": 0, "dp_d2d_bytes": 0, "dp_xfer_bytes": 0,
                       "dp_recv_bytes": 0, "invalidations": 0,
-                      "eager_gathers": 0, "fused_flows": 0,
+                      "eager_gathers": 0, "gather_shapes": 0,
+                      "fused_flows": 0,
                       "wb_tasks": 0, "f64_refused": 0,
                       "spec_store": 0, "spec_hits": 0, "spec_misses": 0,
                       # device pipeline (prefetch lane + residency planner)
@@ -1890,14 +1956,16 @@ class TpuDevice:
                 sp.end(sweep, len(batch) - popped,
                        tasks=len(batch) - popped, closed=closed)
             loop = sp.begin(KEY_DEVICE_LOOP, tp, len(batch))
-            calls = self._calls() if loop is not None else 0
+            if loop is not None:
+                calls, eager = self._calls(), self.stats["eager_gathers"]
             if len(batch) == 1:
                 self._dispatch(task)
             else:
                 self._dispatch_batch(batch)
             if loop is not None:
                 calls = self._calls() - calls
-                sp.end(loop, calls, calls=calls)
+                sp.end(loop, calls, calls=calls,
+                       eager=self.stats["eager_gathers"] - eager)
 
     def _sweep(self, batch: List) -> str:
         """The accumulate sweep: pop more tasks into `batch` while the
@@ -2119,7 +2187,13 @@ class TpuDevice:
 
         Groups are split so one call's stacked operands stay under
         PTC_DEVICE_BATCH_BYTES (default 2 GiB): a wave of wide tiles
-        (panel-granular dense LA) must not stack itself out of HBM."""
+        (panel-granular dense LA) must not stack itself out of HBM.
+        The cap counts a call's padded lanes.  A wave over it is cut
+        into chunks of at most a power-of-two lanes (`_chunks`) along
+        the source stacks its inputs come from, not at fixed multiples
+        of the pop order: a chunk whose flows each come from one stack
+        gathers them inside its program, with no eager copy.  A wave
+        under the cap stays one call."""
         per_task = 0
         # reads + writes separately: an RW flow's gathered input stack
         # and produced output stack coexist during the call, so it costs
@@ -2130,15 +2204,62 @@ class TpuDevice:
             if shp:
                 per_task += int(np.prod(shp)) * np.dtype(
                     body.dtypes.get(f, np.float32)).itemsize
-        if per_task > 0 and len(tasks) * per_task > self.batch_max_bytes:
+        if per_task > 0 and \
+                _bucket(len(tasks)) * per_task > self.batch_max_bytes:
             chunk = max(1, self.batch_max_bytes // per_task)
             # floor to a power of two: _bucket rounds the lane count UP,
             # so a non-power chunk would pad its stacks past the cap
             chunk = 1 << (chunk.bit_length() - 1)
-            for i in range(0, len(tasks), chunk):
-                self._dispatch_group_chunk(body, tasks[i:i + chunk])
+            for part in self._chunks(body, tasks, chunk):
+                self._dispatch_group_chunk(body, part)
             return
         self._dispatch_group_chunk(body, tasks)
+
+    def _chunks(self, body: _DeviceBody, tasks: List,
+                chunk: int) -> List[List]:
+        """A byte-capped wave cut into chunks of at most `chunk` lanes:
+        the tasks whose inputs come from the same source stacks
+        (`_by_source`), cut into full chunks, then next-fit: a group's
+        remainder shares a chunk with the next group only if both fit.
+        Where the wave's sources are the full chunks of the wave before,
+        as on a panel factorization, no chunk mixes sources and the wave
+        costs at most one call more than fixed multiples would; small
+        pieces of a wave split by timing share a chunk (its flows
+        folded into one stack, fold_stack), and the next wave reads
+        that chunk from one stack."""
+        out: List[List] = []
+        run: List = []
+        for part in self._by_source(body, tasks):
+            if len(run) + len(part) <= chunk:
+                run += part
+                continue
+            if run:
+                out.append(run)
+            full = len(part) - len(part) % chunk
+            out += [part[i:i + chunk] for i in range(0, full, chunk)]
+            run = part[full:]
+        if run:
+            out.append(run)
+        return out
+
+    def _by_source(self, body: _DeviceBody, tasks: List) -> List[List]:
+        """`tasks` grouped by the source stack of each read flow's
+        cached input (None for an input that is no stack row): within a
+        group every flow gathers from at most one stack.  Tasks are
+        taken by priority, highest first, so the groups do not depend
+        on the order the engine routed them in."""
+        groups: Dict[tuple, List] = {}
+        for t in sorted(tasks, key=lambda t: -N.lib.ptc_task_priority(t)):
+            view = body.make_view(t)
+            key = []
+            for f in body.reads:
+                _, uid, ver = self._flow_uid_ver(view, body, f)
+                ent = self._cache_ent(uid, ver)
+                a = ent.arr if ent is not None else None
+                key.append(id(a.stack) if isinstance(a, _StackRef)
+                           else None)
+            groups.setdefault(tuple(key), []).append(t)
+        return list(groups.values())
 
     def _prof(self, phase: int, body: "_DeviceBody", lanes: int) -> None:
         """DEVICE_DISPATCH trace span: begin at gather/dispatch start,
@@ -2205,25 +2326,17 @@ class TpuDevice:
                     sig.append("bcast")
                     call_args.append(first)
                 continue
-            one = _single_stack(ents)
-            if one is not None:
-                stack, idxs = one
-                if len(set(idxs)) == 1:
-                    # shared row of one stack: same broadcast case
-                    self.stats["fused_flows"] += 1
-                    sig.append("bidx")
-                    call_args += [stack, np.int32(idxs[0])]
-                    continue
-                idxs += [idxs[0]] * (bucket - len(idxs))
-                sig.append("idx")
+            stack, idxs = self._fold(ents)
+            if len(set(idxs)) == 1:
+                # shared row of one stack: same broadcast case
                 self.stats["fused_flows"] += 1
-                call_args += [stack,
-                              np.asarray(idxs, dtype=np.int32)]
-            else:
-                sig.append(None)
-                self.stats["eager_gathers"] += 1
-                call_args.append(grouped_stack(
-                    self._jax.numpy, ents, bucket))
+                sig.append("bidx")
+                call_args += [stack, np.int32(idxs[0])]
+                continue
+            idxs += [idxs[0]] * (bucket - len(idxs))
+            sig.append("idx")
+            self.stats["fused_flows"] += 1
+            call_args += [stack, np.asarray(idxs, dtype=np.int32)]
         if sig and all(s in ("bcast", "bidx") for s in sig):
             # degenerate wave (every flow shared): vmap needs one
             # mapped axis — demote flow 0 to a per-lane form
@@ -2236,6 +2349,17 @@ class TpuDevice:
                 call_args[0] = self._jax.numpy.stack(
                     [call_args[0]] * bucket)
         return sig, call_args
+
+    def _fold(self, ents: List):
+        """fold_stack of one flow's lanes; a flow no single stack held
+        counts in `eager_gathers`, and the distinct programs such
+        gathers have built on this device in `gather_shapes`."""
+        one = _single_stack(ents)
+        if one is None:
+            one = fold_stack(self._jax.numpy, ents, self._gather_keys)
+            self.stats["eager_gathers"] += 1
+            self.stats["gather_shapes"] = len(self._gather_keys)
+        return one
 
     def _dispatch_group_run(self, body: _DeviceBody, tasks: List):
         if body.spec_src is not None:

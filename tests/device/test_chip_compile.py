@@ -67,7 +67,8 @@ def _programs():
     sigs are those a CPU run of the same DAG dispatches)."""
     import jax
     from parsec_tpu.algos import potrf
-    from parsec_tpu.device.tpu import _get_fused, _get_fused_epi
+    from parsec_tpu.device.tpu import (_get_fused, _get_fused_epi,
+                                       _rows_into)
     from parsec_tpu.ops import flash_attention, rms_norm
     f32, i32 = "float32", "int32"
     nt, lanes = PANEL_N // NB, _panel_lanes()
@@ -86,6 +87,19 @@ def _programs():
                            False, potrf.k_panel_factor, 0, 1),
             [(panel, f32), ((nt, 1), i32), ((lanes,), i32),
              ((lanes,) + panel, f32), ((), i32), ((1,), i32)]),
+        # a capped U(k, *) chunk gathering its targets from one stack of
+        # the wave before, inside the program, with the epilogue lane
+        "panel_update_epilogue_from_stack": (
+            _get_fused_epi(jax, potrf.k_panel_update, ("bcast", "idx", "idx"),
+                           False, potrf.k_panel_factor, 0, 1),
+            [(panel, f32), ((nt, 1), i32), ((lanes,), i32),
+             ((lanes,) + panel, f32), ((lanes,), i32), ((), i32),
+             ((1,), i32)]),
+        # a merged gather: rows of one stack written in place into another
+        "panel_rows_into": (
+            _rows_into(jax),
+            [((lanes,) + panel, f32), ((lanes,) + panel, f32),
+             ((lanes,), i32), ((lanes,), i32), ((), i32)]),
         # the k = 0 wave, gathering its targets from the generator's stack
         "panel_update_from_generator": (
             _get_fused(jax, potrf.k_panel_update, ("bcast", "idx", "idx"),
@@ -116,6 +130,7 @@ def _programs():
 
 @pytest.mark.parametrize("name", [
     "panel_factor", "panel_update_epilogue", "panel_update_from_generator",
+    "panel_update_epilogue_from_stack", "panel_rows_into",
     "tile_potrf_inv", "tile_trsm_mm", "tile_syrk", "tile_gemm",
     "flash_attention", "rms_norm"])
 def test_compiles_for_v5e(compile_for_chip, name):

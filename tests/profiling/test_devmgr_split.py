@@ -191,3 +191,26 @@ def test_traced_benchmark_run_on_the_cpu(tmp_path, monkeypatch):
     # the CPU trace has no device plane: all of the solves is idle
     assert sum(rep["split"][k] for k in SHARES) == pytest.approx(100.0)
     assert rep["split"]["device.idle_share"] == pytest.approx(100.0)
+
+
+def test_traced_run_reports_eager_gathers(tmp_path, monkeypatch):
+    """The tool's line carries the window's eager gathers, summed from
+    the dispatch spans' `eager` args, and the device's gather_shapes."""
+    import jax
+    from parsec_tpu.device import bench_utils
+    bench = _load("bench_conftest", "tests", "bench", "conftest.py")
+    monkeypatch.setattr(bench_utils, "hbm_bytes", lambda d: 8 << 30)
+    root = bench.make_root(tmp_path / "root")
+    stats = {}
+    with contextlib.redirect_stdout(io.StringIO()):
+        tr, spans = S.traced_run(
+            ["--workload", "panel", "--seed", "4", "--seconds", "0.5"],
+            stats, root=root,
+            chip_check=lambda chips: jax.devices()[:chips],
+            peaks=bench.FAKE_PEAKS)
+    rep = S.report(tr, spans, stats)
+    disp = [a for n, _, _, _, a in spans if n == "ptc.devmgr.dispatch"]
+    assert all("eager" in a for a in disp)
+    assert rep["eager_gathers"] == sum(a["eager"] for a in disp)
+    assert 0 <= rep["eager_gathers"] <= stats["eager_gathers"]
+    assert rep["gather_shapes"] == stats["gather_shapes"] >= 0

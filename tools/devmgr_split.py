@@ -28,8 +28,11 @@ clock offset from the native trace clock to the profiler's
 (`profiler_offset_ns` over the dispatch spans: median, spread, and the
 drift between the first and last tenth of the spans), the span counts,
 how the batch_wait sweeps closed (`sweep_close`: % by the engine's
-empty in-flight count, by the deadline, by batch_max), and the host
-spans on the managers' threads that cover the most idle time.  `--dump <file.json.gz>` also writes the reduced trace: busy
+empty in-flight count, by the deadline, by batch_max), the flows the
+window gathered outside their wave program (`eager_gathers`, the
+dispatch spans' `eager` args), with --workload the device's
+`gather_shapes`, and the host spans on the managers' threads that cover
+the most idle time.  `--dump <file.json.gz>` also writes the reduced trace: busy
 intervals, solves and the managers' threads' spans.
 """
 from __future__ import annotations
@@ -150,14 +153,21 @@ def top_idle_spans(tr: T.Trace, spans, n: int = 25) -> list:
                   key=lambda kv: -kv[1])[:n]
 
 
-def report(tr: T.Trace, spans) -> dict:
+def report(tr: T.Trace, spans, stats=None) -> dict:
+    """The split and the rest of the result line; `stats`, the device's
+    dev.stats at the end of the run, gives `gather_shapes`."""
     counts = {}
     for name, *_ in spans:
         if name.startswith("ptc.devmgr."):
             counts[name] = counts.get(name, 0) + 1
-    return {"split": split(tr, spans), "clock": clock(spans),
-            "spans": counts, "sweep_close": sweep_close(spans),
-            "top_idle_spans": top_idle_spans(tr, spans)}
+    out = {"split": split(tr, spans), "clock": clock(spans),
+           "spans": counts, "sweep_close": sweep_close(spans),
+           "eager_gathers": sum(a.get("eager", 0) for n, _, _, _, a in spans
+                                if n == "ptc.devmgr.dispatch"),
+           "top_idle_spans": top_idle_spans(tr, spans)}
+    if stats is not None:
+        out["gather_shapes"] = stats.get("gather_shapes")
+    return out
 
 
 def dump(path: str, tr: T.Trace, spans) -> None:
@@ -179,13 +189,20 @@ def undump(path: str):
     return tr, [tuple(s) for s in d["spans"]]
 
 
-def traced_run(argv, **main_kw) -> tuple:
+def traced_run(argv, stats: dict = None, **main_kw) -> tuple:
     """One `benchmark/run.py --trace 1` run in this process (`main_kw`
     go to its main()); its trace (trace_reduce.Trace) and host spans,
-    read before run.py removes the file."""
-    from benchmark import run
+    read before run.py removes the file.  `stats`, a dict, takes the
+    device's dev.stats as the run stops it."""
+    from benchmark import harness, run
     kept = {}
     load = T.Trace.load.__func__
+    finish = harness.Rank.finish
+
+    def keep_stats(rank):
+        if stats is not None:
+            stats.update(rank.dev.stats)
+        finish(rank)
 
     def keep(cls, trace_dir, nchips):
         tr = load(cls, trace_dir, nchips)
@@ -196,10 +213,12 @@ def traced_run(argv, **main_kw) -> tuple:
         return tr
 
     T.Trace.load = classmethod(keep)
+    harness.Rank.finish = keep_stats
     try:
         rc = run.main(argv + ["--trace", "1"], **main_kw)
     finally:
         T.Trace.load = classmethod(load)
+        harness.Rank.finish = finish
     if rc or "trace" not in kept:
         raise SystemExit(rc or 1)
     return kept["trace"], kept["spans"]
@@ -214,15 +233,17 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=51)
     ap.add_argument("--dump", help="write the reduced trace here")
     args = ap.parse_args(argv)
+    stats = None
     if args.workload:
+        stats = {}
         tr, spans = traced_run(["--workload", args.workload, "--seed",
                                 str(args.seed), "--seconds",
-                                str(args.seconds)])
+                                str(args.seconds)], stats)
     else:
         tr, spans = undump(args.dump_in)
     if args.dump:
         dump(args.dump, tr, spans)
-    print(json.dumps(report(tr, spans)), flush=True)
+    print(json.dumps(report(tr, spans, stats)), flush=True)
     return 0
 
 
