@@ -13,6 +13,7 @@ matching the reference's priority-expression practice in dense LA JDFs.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import numpy as np
@@ -276,13 +277,90 @@ def k_panel_factor(p, ks):
 
 
 def k_panel_update(pk, js, pj):
+    """U(k, j): rows of panel j at and below its diagonal block, j nb,
+    get pj - pk bj^T (bj = pk's row block j); the rows above come back
+    as they came in.  Only those rows are ever read: F(j) zeroes the
+    rest, and pk's rows above k nb are zero.
+
+    The update runs in row blocks of panel_row_block(N) rows, from the
+    block holding the first needed row down, in one device loop.  Under
+    vmap (a U wave) the loop starts at the wave's least j, so a wave of
+    lanes multiplies each lane's rows from there down and every lane's
+    rows at and below its own j nb come out the same whichever lanes
+    share the wave."""
+    return _panel_update_blocked()(pk, js, pj)
+
+
+def panel_row_block(n: int) -> int:
+    """Rows in one block of the panel update's loop: n / 16, or the
+    nearest coarser split of n where 16 does not divide it."""
+    return n // int(np.gcd(n, 16))
+
+
+def _update_rows(pk, pk_lanes: bool, offs, pj):
+    """The row-blocked update of lanes pj [L, n, nb] from their first
+    rows offs [L]: pk is [n, nb], shared by every lane, or [L, n, nb]
+    when pk_lanes.  One lax loop over row blocks from min(offs) down;
+    each step multiplies one block of pk against every lane's bj at
+    MATMUL_PRECISION and writes the block back in place, with a
+    per-lane mask that keeps rows above a lane's own offset."""
     import jax
-    nb = pk.shape[1]
-    off = js[0] * nb
-    bj = jax.lax.dynamic_slice(pk, (off, 0), (nb, nb))
-    return pj - jax.lax.dot_general(pk, bj, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=pj.dtype,
-                                    precision=MATMUL_PRECISION)
+    import jax.numpy as jnp
+    n, nb = pj.shape[1:]
+    rb = panel_row_block(n)
+
+    def diag(p, off):
+        return jax.lax.dynamic_slice(p, (off, 0), (nb, nb))
+
+    bj = (jax.vmap(diag)(pk, offs) if pk_lanes
+          else jax.vmap(diag, in_axes=(None, 0))(pk, offs))
+
+    def step(b, acc):
+        r = b * rb
+        blk = jax.lax.dynamic_slice_in_dim(pk, r, rb, axis=pk.ndim - 2)
+        if pk_lanes:
+            prod = jax.lax.dot_general(blk, bj, (((2,), (2,)), ((0,), (0,))),
+                                       preferred_element_type=pj.dtype,
+                                       precision=MATMUL_PRECISION)
+        else:
+            # [rb, L, nb], lanes moved to the front: the layout XLA gives
+            # the lanes of a vmapped dot, so the subtract fuses with it
+            prod = jnp.moveaxis(jax.lax.dot_general(
+                blk, bj, (((1,), (2,)), ((), ())),
+                preferred_element_type=pj.dtype,
+                precision=MATMUL_PRECISION), 1, 0)
+        cur = jax.lax.dynamic_slice_in_dim(acc, r, rb, axis=1)
+        rows = r + jnp.arange(rb, dtype=offs.dtype)
+        keep = rows[None, :, None] >= offs[:, None, None]
+        return jax.lax.dynamic_update_slice_in_dim(
+            acc, jnp.where(keep, cur - prod, cur), r, axis=1)
+
+    return jax.lax.fori_loop(jnp.min(offs) // rb, n // rb, step, pj)
+
+
+@functools.lru_cache(maxsize=None)
+def _panel_update_blocked():
+    """k_panel_update's custom_vmap, built once on first use (jax is
+    imported lazily).  The primal is the single-lane form; the vmap
+    rule sees the whole wave, so its loop bounds stay unbatched and the
+    loop stays a loop."""
+    import jax.numpy as jnp
+    from jax.custom_batching import custom_vmap
+
+    @custom_vmap
+    def update(pk, js, pj):
+        return _update_rows(pk, False, js[:1] * pk.shape[1], pj[None])[0]
+
+    @update.def_vmap
+    def update_wave(lanes, in_batched, pk, js, pj):
+        pk_lanes, js_lanes, pj_lanes = in_batched
+        if not js_lanes:
+            js = jnp.broadcast_to(js, (lanes,) + js.shape)
+        if not pj_lanes:
+            pj = jnp.broadcast_to(pj, (lanes,) + pj.shape)
+        return _update_rows(pk, pk_lanes, js[:, 0] * pk.shape[-1], pj), True
+
+    return update
 
 
 def _register_pidx(ctx: pt.Context, A: TwoDimBlockCyclic, name: str):

@@ -571,7 +571,12 @@ def _sig_assemble(jnp, sig, args):
     ins, ai = [], 0
     for s in sig:
         if s in ("idx", "bidx"):
-            ins.append(jnp.take(args[ai], args[ai + 1], axis=0))
+            # the rows are the manager's own, in range: "clip" leaves the
+            # gather without take's out-of-range select, which XLA cannot
+            # fuse into a kernel that loops over its input (a full extra
+            # pass over every panel lane on a v5e)
+            ins.append(jnp.take(args[ai], args[ai + 1], axis=0,
+                                mode="clip"))
             ai += 2
         else:  # "bcast" / pre-stacked passthrough
             ins.append(args[ai])

@@ -143,3 +143,20 @@ def test_compiles_for_v5e(compile_for_chip, name):
     if name in ("flash_attention", "rms_norm"):
         # interpret mode is the backend's choice: the Mosaic kernel is in
         assert "tpu_custom_call" in exe.as_text()
+
+
+@pytest.mark.parametrize("name", [
+    "panel_update_epilogue", "panel_update_from_generator",
+    "panel_update_epilogue_from_stack"])
+def test_panel_update_runs_by_row_block(compile_for_chip, name):
+    """A U wave's program multiplies row blocks of N/16 rows in a loop,
+    the dot and its subtract in one fusion, and gathers its targets with
+    no full-height select beside the loop."""
+    import re
+    fn, specs = _programs()[name]
+    text = compile_for_chip(fn, *specs).as_text()
+    lanes = _panel_lanes()
+    assert re.search(rf"subtract_select_fusion(\.\d+)? = "
+                     rf"f32\[{lanes},{PANEL_N // 16},{NB}\]", text)
+    assert not re.search(rf"select_fusion(\.\d+)? = "
+                         rf"f32\[{lanes},{PANEL_N},{NB}\]", text)

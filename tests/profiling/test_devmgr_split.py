@@ -143,6 +143,46 @@ def test_sweep_close_shares_from_the_closed_arg():
     assert S.sweep_close(spans[4:]) == {}
 
 
+def test_update_rows_per_useful_row_from_the_update_ops():
+    """Lanes times rows of each panel update op run inside the solves
+    (the whole-panel fusion of a full-height update, or one row block of
+    a row-blocked one), per row the solves need; no other op counts."""
+    cfg = {"dag": "panels", "N": 2048, "NB": 128}
+    ops = [
+        # solve 1: a full-height 8-lane wave, a lone lane, one row block
+        # of a 4-lane wave
+        ("%bitcast_subtract_fusion.3 = f32[8,2048,128]{2,1,0:T(8,128)} "
+         "fusion(f32[2048,128]{1,0} %p), kind=kOutput, calls=%f.3", 0, 10),
+        ("%bitcast_subtract_fusion = f32[2048,128]{1,0:T(8,128)} "
+         "fusion(f32[2048,128]{1,0} %p), kind=kOutput, calls=%f.1", 20, 30),
+        ("%subtract_select_fusion.2 = f32[4,128,128]{2,1,0:T(8,128)} "
+         "fusion(f32[4,2048,128]{2,1,0} %a), kind=kOutput, calls=%f.2",
+         40, 50),
+        # a subtract without a dot, the factor's dot, a tile update
+        ("%subtract_fusion = f32[8,2048,128]{2,1,0} fusion(), kind=kLoop",
+         60, 70),
+        ("%fusion.70 = f32[2048,128]{1,0} fusion(), kind=kOutput", 80, 90),
+        ("%convolution_subtract_fusion = f32[128,256]{1,0} fusion(), "
+         "kind=kOutput", 100, 110),
+        # solve 2: one row block of 8 lanes; then one after the solves
+        ("%subtract_select_fusion.2 = f32[8,256,128]{2,1,0} fusion(), "
+         "kind=kOutput", 1100, 1200),
+        ("%subtract_select_fusion.2 = f32[8,256,128]{2,1,0} fusion(), "
+         "kind=kOutput", 2500, 2600),
+    ]
+    host = [("window", 0, 3000), ("solve", 0, 1000), ("solve", 1000, 2000)]
+    tr = T.Trace({0: ops}, host, 1)
+    useful = sum(j * (2048 - j * 128) for j in range(16))
+    rows = 8 * 2048 + 2048 + 4 * 128 + 8 * 256
+    assert S.update_rows(tr, cfg) == pytest.approx(rows / useful / 2)
+    rep = S.report(tr, [], cfg=cfg)
+    assert rep["update_rows"] == pytest.approx(rows / useful / 2)
+    assert "update_rows" not in S.report(tr, [])
+    # a tile cell, or a panel trace with no update op, reads nothing
+    assert S.update_rows(tr, dict(cfg, dag="tiles")) is None
+    assert S.update_rows(T.Trace({0: ops[3:6]}, host, 1), cfg) is None
+
+
 def test_recorded_trace_without_manager_spans_is_all_rest():
     tr = T.Trace.from_file(FIXTURE, 1)
     spans = S.xplane_spans(FIXTURE, "ptc.devmgr.", threads=True)
@@ -201,14 +241,17 @@ def test_traced_run_reports_eager_gathers(tmp_path, monkeypatch):
     bench = _load("bench_conftest", "tests", "bench", "conftest.py")
     monkeypatch.setattr(bench_utils, "hbm_bytes", lambda d: 8 << 30)
     root = bench.make_root(tmp_path / "root")
-    stats = {}
+    stats, cfg = {}, {}
     with contextlib.redirect_stdout(io.StringIO()):
         tr, spans = S.traced_run(
             ["--workload", "panel", "--seed", "4", "--seconds", "0.5"],
-            stats, root=root,
+            stats, cfg, root=root,
             chip_check=lambda chips: jax.devices()[:chips],
             peaks=bench.FAKE_PEAKS)
-    rep = S.report(tr, spans, stats)
+    rep = S.report(tr, spans, stats, cfg)
+    assert (cfg["dag"], cfg["N"], cfg["NB"]) == ("panels", 1024, 128)
+    # the CPU trace has no device plane: no update op to count
+    assert rep["update_rows"] is None
     disp = [a for n, _, _, _, a in spans if n == "ptc.devmgr.dispatch"]
     assert all("eager" in a for a in disp)
     assert rep["eager_gathers"] == sum(a["eager"] for a in disp)

@@ -31,22 +31,27 @@ how the batch_wait sweeps closed (`sweep_close`: % by the engine's
 empty in-flight count, by the deadline, by batch_max), the flows the
 window gathered outside their wave program (`eager_gathers`, the
 dispatch spans' `eager` args), with --workload the device's
-`gather_shapes`, and the host spans on the managers' threads that cover
-the most idle time.  `--dump <file.json.gz>` also writes the reduced trace: busy
-intervals, solves and the managers' threads' spans.
+`gather_shapes` and, in a panel cell, the panel update's rows the
+device multiplied per row the factorization needs (`update_rows`), and
+the host spans on the managers' threads that cover the most idle time.
+`--dump <file.json.gz>` also writes the reduced trace: busy intervals,
+solves and the managers' threads' spans.
 """
 from __future__ import annotations
 
 import argparse
+import bisect
 import glob
 import gzip
 import json
 import os
+import re
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+from benchmark import flops  # noqa: E402
 from benchmark import trace_reduce as T  # noqa: E402
 from parsec_tpu.profiling.trace import (profiler_offset_ns,  # noqa: E402
                                         xplane_spans)
@@ -59,6 +64,11 @@ BUILD_SPANS = ("trace_to_jaxpr_dynamic", "lower_sharding_computation",
 # engine had nothing left in flight, the batch_wait_ms deadline passed,
 # or the batch reached batch_max
 CLOSES = ("quiesced", "deadline", "full")
+# the panel update's ops in a v5e trace: the fusion that holds the
+# update's dot and its subtract, f32[lanes, rows, NB] or f32[rows, NB]
+# (all N rows of a panel, or one row block of them), named by its HLO
+# text as trace_reduce reads it
+UPDATE_OP = r"^%?\w*subtract\w*_fusion(\.\d+)? = f32\[(?:(\d+),)?(\d+),{NB}\]"
 STATES = (("devmgr.build_idle", None),
           ("devmgr.batch_wait_idle", "ptc.devmgr.batch_wait"),
           ("devmgr.dispatch_idle", "ptc.devmgr.dispatch"),
@@ -135,6 +145,29 @@ def sweep_close(spans) -> dict:
     return out
 
 
+def update_rows(tr: T.Trace, cfg: dict):
+    """The panel update's rows the device multiplied inside the solves,
+    lanes times rows of each update op run, per useful row: the rows at
+    and below block row j of each U(k, j) (benchmark/flops.py's count
+    over 2 NB^2).  None outside a panel cell or where no op matches."""
+    if cfg.get("dag") != "panels" or not tr.solves:
+        return None
+    nb = cfg["NB"]
+    pat = re.compile(UPDATE_OP.replace("{NB}", str(nb)))
+    starts = [s for s, _ in tr.solves]
+    rows = 0
+    for ops in tr.ops.values():
+        for text, s, _ in ops:
+            m = pat.search(text)
+            if not m or "kind=kOutput" not in text:
+                continue
+            i = bisect.bisect_right(starts, s) - 1
+            if i >= 0 and s < tr.solves[i][1]:
+                rows += int(m.group(2) or 1) * int(m.group(3))
+    useful = flops.panel_update(cfg["N"], nb) / (2 * nb * nb)
+    return rows / useful / len(tr.solves) if rows else None
+
+
 def top_idle_spans(tr: T.Trace, spans, n: int = 25) -> list:
     """The names of the spans on the managers' threads whose union
     covers the most idle time inside the solves: [name, seconds]."""
@@ -153,9 +186,10 @@ def top_idle_spans(tr: T.Trace, spans, n: int = 25) -> list:
                   key=lambda kv: -kv[1])[:n]
 
 
-def report(tr: T.Trace, spans, stats=None) -> dict:
+def report(tr: T.Trace, spans, stats=None, cfg=None) -> dict:
     """The split and the rest of the result line; `stats`, the device's
-    dev.stats at the end of the run, gives `gather_shapes`."""
+    dev.stats at the end of the run, gives `gather_shapes`, and `cfg`,
+    the cell's configuration, `update_rows`."""
     counts = {}
     for name, *_ in spans:
         if name.startswith("ptc.devmgr."):
@@ -167,6 +201,8 @@ def report(tr: T.Trace, spans, stats=None) -> dict:
            "top_idle_spans": top_idle_spans(tr, spans)}
     if stats is not None:
         out["gather_shapes"] = stats.get("gather_shapes")
+    if cfg is not None:
+        out["update_rows"] = update_rows(tr, cfg)
     return out
 
 
@@ -189,11 +225,13 @@ def undump(path: str):
     return tr, [tuple(s) for s in d["spans"]]
 
 
-def traced_run(argv, stats: dict = None, **main_kw) -> tuple:
+def traced_run(argv, stats: dict = None, cfg: dict = None,
+               **main_kw) -> tuple:
     """One `benchmark/run.py --trace 1` run in this process (`main_kw`
     go to its main()); its trace (trace_reduce.Trace) and host spans,
     read before run.py removes the file.  `stats`, a dict, takes the
-    device's dev.stats as the run stops it."""
+    device's dev.stats as the run stops it, and `cfg`, a dict, the
+    cell's configuration."""
     from benchmark import harness, run
     kept = {}
     load = T.Trace.load.__func__
@@ -202,6 +240,8 @@ def traced_run(argv, stats: dict = None, **main_kw) -> tuple:
     def keep_stats(rank):
         if stats is not None:
             stats.update(rank.dev.stats)
+        if cfg is not None:
+            cfg.update(rank.cfg)
         finish(rank)
 
     def keep(cls, trace_dir, nchips):
@@ -233,17 +273,17 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=51)
     ap.add_argument("--dump", help="write the reduced trace here")
     args = ap.parse_args(argv)
-    stats = None
+    stats = cfg = None
     if args.workload:
-        stats = {}
+        stats, cfg = {}, {}
         tr, spans = traced_run(["--workload", args.workload, "--seed",
                                 str(args.seed), "--seconds",
-                                str(args.seconds)], stats)
+                                str(args.seconds)], stats, cfg)
     else:
         tr, spans = undump(args.dump_in)
     if args.dump:
         dump(args.dump, tr, spans)
-    print(json.dumps(report(tr, spans, stats)), flush=True)
+    print(json.dumps(report(tr, spans, stats, cfg)), flush=True)
     return 0
 
 
