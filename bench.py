@@ -765,9 +765,7 @@ def _pair_spans(ev, key, aux_filter=None):
     """(t0, t1, l0, end_aux, begin_aux) tuples from consecutive
     begin/end events of one trace key.  DEVICE and H2D spans are
     emitted by single threads (manager / prefetch lane), so
-    time-ordered pairing is exact.  DEVICE begin aux carries the
-    ptc-fuse mark (0 plain, n >= 1 = a certified wave executable
-    covering n waves)."""
+    time-ordered pairing is exact."""
     rows = ev[ev[:, 0] == key]
     if aux_filter is not None:
         rows = rows[rows[:, 6] == aux_filter]
@@ -957,96 +955,6 @@ def bench_device_ooc_gemm(m=512, n=512, k=64, mb=32):
     }
 
 
-def _fuse_gemm_run(fuse, m, k, nb, batch_wait_ms=2.0):
-    """One wave-fusion GEMM run: single-rank owner-computes k-chain
-    (kt = k/nb waves of (m/nb)^2 Gemm tasks).  Returns (C dense,
-    DEVICE launch count, fused-marked launch count, fuse counters,
-    wall_s)."""
-    from parsec_tpu.algos import build_gemm
-    from parsec_tpu.data import TwoDimBlockCyclic
-    from parsec_tpu.device import TpuDevice
-    from parsec_tpu.profiling.trace import KEY_DEVICE
-    from parsec_tpu.utils import params as _mca
-    _mca.set("device.wave_fuse", bool(fuse))
-    try:
-        rng = np.random.default_rng(5)
-        with pt.Context(nb_workers=2) as ctx:
-            A = TwoDimBlockCyclic(m, k, nb, nb, dtype=np.float32)
-            B = TwoDimBlockCyclic(k, m, nb, nb, dtype=np.float32)
-            Cc = TwoDimBlockCyclic(m, m, nb, nb, dtype=np.float32)
-            A.from_dense(rng.standard_normal((m, k), dtype=np.float32))
-            B.from_dense(rng.standard_normal((k, m), dtype=np.float32))
-            Cc.from_dense(np.zeros((m, m), np.float32))
-            A.register(ctx, "A")
-            B.register(ctx, "B")
-            Cc.register(ctx, "C")
-            ctx.profile_enable(1)
-            dev = TpuDevice(ctx)
-            # coalesce whole waves per pop (the spotrf bench setting):
-            # launch economics, not pop-timing luck, is under test
-            dev.batch_wait_ms = batch_wait_ms
-            tp = build_gemm(ctx, A, B, Cc, dev=dev)
-            t0 = time.perf_counter()
-            tp.run()
-            tp.wait()
-            dev.flush()
-            wall = time.perf_counter() - t0
-            ev = ctx.profile_take()
-            stats = ctx.device_stats()
-            dev.stop()
-            out = Cc.to_dense().copy()
-        spans = _pair_spans(ev, KEY_DEVICE)
-        fused_marked = sum(1 for s in spans if s[4] > 0)
-        return out, len(spans), fused_marked, stats["fuse"], wall
-    finally:
-        _mca.unset("device.wave_fuse")
-
-
-def bench_device_fuse_gemm(m=128, k=512, nb=32, reps=3):
-    """Wave mega-kernelization section (`make bench-device`): the SAME
-    deep-k GEMM runs with the wave compiler ON (certified waves +
-    chains compile into one cached executable each; downstream waves
-    complete from parked results with zero launches) and OFF
-    (PTC_MCA_device_wave_fuse=0 — the PR 12 per-group batched path).
-    Launch counts come straight off paired DEVICE spans; acceptance is
-    >= 5x fewer launches at BIT-EXACT results (the equal-direction
-    gate bench_check never relaxes)."""
-    tasks = (m // nb) ** 2 * (k // nb)
-    best_f = best_u = None
-    bit_identical = True
-    fuse_stats = None
-    for _ in range(reps):
-        cf, lf, marked, fs, wf = _fuse_gemm_run(True, m, k, nb)
-        cu, lu, _, _, wu = _fuse_gemm_run(False, m, k, nb)
-        bit_identical = bit_identical and \
-            (cf.tobytes() == cu.tobytes())
-        # fewest launches first, then wall (rep 0 pays the one-time
-        # chain-program compile; the cache makes later reps steady-state)
-        if best_f is None or (lf, wf) < (best_f[0], best_f[2]):
-            best_f = (lf, marked, wf)
-            fuse_stats = fs
-        if best_u is None or (lu, wu) < best_u:
-            best_u = (lu, wu)
-    launches_f, marked, wall_f = best_f
-    launches_u, wall_u = best_u
-    return {
-        "m": m, "k": k, "nb": nb, "reps": reps,
-        "tasks": tasks,
-        "waves": k // nb,
-        "launches_fused": launches_f,
-        "launches_unfused": launches_u,
-        "fused_marked_launches": marked,
-        # the two bench_check trajectory rows + the correctness gate
-        "launches_per_task": round(launches_f / tasks, 5),
-        "fused_vs_unfused_ratio": round(launches_u
-                                        / max(1, launches_f), 2),
-        "bit_identical": bit_identical,
-        "wall_fused_s": round(wall_f, 4),
-        "wall_unfused_s": round(wall_u, 4),
-        "fuse_stats": {kk: vv for kk, vv in (fuse_stats or {}).items()},
-    }
-
-
 def bench_device_suite(tiles=96, elems=32 * 1024, batch=8, reps=3,
                        gemm_m=512, gemm_k=64, gemm_mb=32):
     """The `make bench-device` document (BENCH_device.json): staged-vs-
@@ -1069,9 +977,6 @@ def bench_device_suite(tiles=96, elems=32 * 1024, batch=8, reps=3,
         "wave_pipeline": bench_device_pipeline(tiles, elems, batch, reps),
         "out_of_core_gemm": bench_device_ooc_gemm(
             m=gemm_m, n=gemm_m, k=gemm_k, mb=gemm_mb),
-        # ptc-fuse: wave mega-kernelization launch economics (>= 5x
-        # fewer DEVICE launches at bit-exact results is the gate)
-        "wave_fuse": bench_device_fuse_gemm(),
     }
     if doc["oversubscribed"]:
         doc["caveat"] = (
@@ -1932,7 +1837,7 @@ def bench_serve_suite(n_hi=6, n_lo=18, max_new=6, workers=2, seed=0,
 
     # 8 virtual host devices BEFORE the first jax backend use: the tp
     # section pins one per colocated rank (up to 4) and the spec
-    # section's fused-verify run takes device 0
+    # section's batched-verify run takes device 0
     import os
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
@@ -2078,8 +1983,8 @@ def bench_serve_suite(n_hi=6, n_lo=18, max_new=6, workers=2, seed=0,
         # trajectory-guarded timing
         "scope": _scope_bench_section(qos_scope),
         # ptc-share: shared-prefix KV cache (cold vs warm prompt mix)
-        # and speculative decoding (off / k=2 / k=4 + the fused verify
-        # wave) — the bit_identical flags are equal-direction
+        # and speculative decoding (off / k=2 / k=4 + the batched
+        # verify wave) — the bit_identical flags are equal-direction
         # correctness rows bench_check NEVER relaxes; hit-rate and
         # tokens/s are oversubscription-slacked timing trajectory rows
         "prefix": _prefix_bench_section(model, workers=workers),
@@ -2091,11 +1996,10 @@ def bench_serve_suite(n_hi=6, n_lo=18, max_new=6, workers=2, seed=0,
         # correctness row bench_check NEVER relaxes
         "fleet": _fleet_bench_section(model, workers=workers),
         # ptc-shard: 2- and 4-rank tensor-parallel PagedLM vs the
-        # single-rank reference — bit_identical and the every-rank-
-        # fused-waves verdict are equal-direction correctness flags
-        # bench_check NEVER relaxes; the per-token wall ratio is an
-        # oversubscription-slacked timing trajectory row (all ranks
-        # timeshare this host)
+        # single-rank reference — bit_identical is an equal-direction
+        # correctness flag bench_check NEVER relaxes; the per-token
+        # wall ratio is an oversubscription-slacked timing trajectory
+        # row (all ranks timeshare this host)
         "tp": _tp_bench_section(workers=workers),
     })
     if oversub:
@@ -2250,7 +2154,7 @@ def _spec_bench_section(model, workers=2, n_reqs=8, max_new=8, seed=23):
                 wall = time.perf_counter() - t0
                 st = dict(eng.stats)
                 serve_spec = eng._spec_stats()
-                fuse = ctx.device_stats().get("fuse", {}) if dev else {}
+                dstats = dict(dev_obj.stats) if dev else {}
                 ev = ctx.profile_take() if trace else None
                 eng.close()
             finally:
@@ -2264,7 +2168,7 @@ def _spec_bench_section(model, workers=2, n_reqs=8, max_new=8, seed=23):
             "decode_waves": st["decode_pools"],
             "accept_rate": round(serve_spec["accept_rate"], 4),
             "fallbacks": st["spec_fallbacks"],
-        }, [(h.tokens, np.stack(h.outputs)) for h in hs], fuse, ev
+        }, [(h.tokens, np.stack(h.outputs)) for h in hs], dstats, ev
 
     base, base_outs, _, _ = run_k(0)
     out = {"off": base}
@@ -2280,19 +2184,18 @@ def _spec_bench_section(model, workers=2, n_reqs=8, max_new=8, seed=23):
     out["bit_identical"] = bit_identical
     out["fewer_waves_than_off"] = bool(
         out["k4"]["decode_waves"] < base["decode_waves"])
-    # fused verify wave: DEVICE span evidence (device folds = VATF
+    # batched verify wave: DEVICE span evidence (device folds = VATF
     # verification only; PATTL/VATL run host-side)
-    vdoc, _, fuse, ev = run_k(4, dev=True, trace=True)
+    vdoc, _, dstats, ev = run_k(4, dev=True, trace=True)
     spans = _pair_spans(ev, KEY_DEVICE) if ev is not None else []
-    fused_marked = sum(1 for s in spans if s[4] > 0)
+    batches = dstats.get("batches", 0)
+    batched_tasks = dstats.get("batched_tasks", 0)
     out["verify_wave"] = {
         "device_launches": len(spans),
-        "fused_marked_launches": fused_marked,
-        "fused_waves": fuse.get("fused_waves", 0),
-        "fused_tasks": fuse.get("fused_tasks", 0),
-        "single_fused_launch": bool(
-            fuse.get("fused_waves", 0) > 0 and
-            fuse.get("fused_tasks", 0) > fuse.get("fused_waves", 0)),
+        "batches": batches,
+        "batched_tasks": batched_tasks,
+        "single_batched_launch": bool(
+            batches > 0 and batched_tasks > batches),
         "tokens_per_s": vdoc["tokens_per_s"],
     }
     return out
@@ -2418,12 +2321,11 @@ def _tp_bench_section(workers=2, max_new=6, n_reqs=3, seed=23,
       tpN.ms_per_token  decode wall per generated token; flat-ish as tp
                       grows is the win, but all ranks timeshare one
                       host so this is oversubscription-slacked timing
-      tpN.fused_waves per-rank PR 13 wave-compiler counts from a
-                      separate device-attached run of the same mix
-                      (each rank certifies + fuses ITS OWN shard of
-                      the batched verify wave); all_ranks_fused is the
-                      fused_waves>0-on-every-rank verdict —
-                      equal-direction, never relaxed
+      tpN.batches     per-rank batched device launches (`batches`)
+                      from a separate device-attached run of the same
+                      mix (each rank batches ITS OWN shard of the
+                      verify wave); all_ranks_batched is the
+                      batches>0-on-every-rank verdict
       tpN.coll_wait_ms  total engine stall on the embedded collective
     """
     import threading
@@ -2482,8 +2384,7 @@ def _tp_bench_section(workers=2, max_new=6, n_reqs=3, seed=23,
                         hs = drive(eng)
                         wall = time.perf_counter() - t0
                         st = dict(eng.stats)
-                        fuse = (ctx.device_stats().get("fuse", {})
-                                if with_dev else {})
+                        dstats = dict(dev.stats) if with_dev else {}
                         toks = [list(h.tokens) for h in hs]
                         outs = [[o.copy() for o in h.outputs]
                                 for h in hs]
@@ -2493,7 +2394,7 @@ def _tp_bench_section(workers=2, max_new=6, n_reqs=3, seed=23,
                             dev.stop()
                     ctx.comm_fence()
                     ctx.comm_fini()
-                results[rank] = ("ok", toks, outs, wall, st, fuse)
+                results[rank] = ("ok", toks, outs, wall, st, dstats)
             except Exception:
                 import traceback
                 results[rank] = ("err", traceback.format_exc(),
@@ -2532,7 +2433,7 @@ def _tp_bench_section(workers=2, max_new=6, n_reqs=3, seed=23,
     doc = {"requests": len(reqs), "tokens": tokens,
            "heads": cfg.heads, "d": cfg.d,
            "tp1": {"ms_per_token": round(ref_wall * 1e3 / tokens, 3)}}
-    all_fused = True
+    all_batched = True
     for i, nodes in enumerate((2, 4)):
         res = run_group(nodes, base_port + 4 * i)
         # every rank's tokens + reduced pre-logit bytes must equal the
@@ -2547,19 +2448,19 @@ def _tp_bench_section(workers=2, max_new=6, n_reqs=3, seed=23,
         wall = max(res[r][3] for r in range(nodes))
         st = res[0][4]
         fres = run_group(nodes, base_port + 4 * i + 2, with_dev=True)
-        fused = [fres[r][5].get("fused_waves", 0) for r in range(nodes)]
-        if not all(f > 0 for f in fused):
-            all_fused = False
+        batches = [fres[r][5].get("batches", 0) for r in range(nodes)]
+        if not all(b > 0 for b in batches):
+            all_batched = False
         doc[f"tp{nodes}"] = {
             "ms_per_token": round(wall * 1e3 / tokens, 3),
             "coll_pools": st["tp_coll_pools"],
             "coll_wait_ms": round(st["tp_coll_wait_ns"] / 1e6, 3),
             "prefix_hits": st["prefix_hits"],
             "spec_accepted": st["spec_accepted"],
-            "fused_waves": fused,
+            "batches": batches,
         }
     doc["bit_identical"] = bit_identical
-    doc["all_ranks_fused"] = all_fused
+    doc["all_ranks_batched"] = all_batched
     doc["tp4_vs_tp1_ms_per_token"] = round(
         doc["tp4"]["ms_per_token"] / max(1e-9,
                                          doc["tp1"]["ms_per_token"]), 3)

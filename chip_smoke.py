@@ -18,7 +18,7 @@ Each phase runs twice (the first run compiles; the second is checked)
 and fails the script unless
   (a) the device executed every task of the DAG (a count from NT), so
       no task ran as a CPU chore;
-  (b) no wave fell back to per-task dispatch and no fused chain failed;
+  (b) no wave fell back to per-task dispatch;
   (c) the runtime printed no `ptc:` warning;
   (d) ||L(L^T x) - Ax|| / (||A||_1 ||x||) for seeded random x, on the
       device at HIGHEST, and the float64 host check of a few blocks of
@@ -89,20 +89,18 @@ def _panel_tasks_of_rank(nt, nranks, r):
 
 def _device_report(dev):
     s = dev.stats
-    fz = dev._fuser.snapshot() if dev._fuser is not None else {}
     keys = ("tasks", "batches", "batched_tasks", "spec_hits", "h2d_bytes",
             "d2h_bytes", "d2d_bytes", "dp_d2d_bytes", "dp_recv_bytes",
             "spills", "batch_fallbacks", "cache_peak_bytes")
-    return {k: s[k] for k in keys}, fz.get("refused", {})
+    return {k: s[k] for k in keys}
 
 
-def _phase_failures(st, refused, warnings, want_tasks):
+def _phase_failures(st, warnings, want_tasks):
     bad = []
     if st["tasks"] != want_tasks:
         bad.append(f"(a) device ran {st['tasks']} of {want_tasks} tasks")
-    if st["batch_fallbacks"] or refused.get("chain:error"):
-        bad.append(f"(b) batch_fallbacks={st['batch_fallbacks']} "
-                   f"chain:error={refused.get('chain:error', 0)}")
+    if st["batch_fallbacks"]:
+        bad.append(f"(b) batch_fallbacks={st['batch_fallbacks']}")
     if warnings:
         bad.append(f"(c) runtime warnings: {warnings[:5]}")
     return bad
@@ -158,18 +156,16 @@ def run_phase(name, variant, n, nb):
             tp.wait()
             wait_device_tiles(dev, A)
             wall = time.perf_counter() - t0
-            st, refused = _device_report(dev)
+            st = _device_report(dev)
             print(f"{name} {what}: N={n} NB={nb} tasks expected {want}, "
                   f"generate {t_gen:.2f} s, factor wall {wall:.2f} s",
                   flush=True)
             print(f"{name} {what}: dev.stats {json.dumps(st)}", flush=True)
-            print(f"{name} {what}: fuser refused {json.dumps(refused)}",
-                  flush=True)
             if rep == 1:
                 bad += _check([(dev, A)], n, name)
             dev.stop()
         bad += [f"{what}: {b}" for b in
-                _phase_failures(st, refused, watch.lines, want)]
+                _phase_failures(st, watch.lines, want)]
     peak = (dev.device.memory_stats() or {}).get("peak_bytes_in_use")
     print(f"{name}: device peak_bytes_in_use {peak}", flush=True)
     return bad
@@ -250,10 +246,9 @@ def run_colocated_phase(name, n, nb, nranks):
                 print(f"{name} {what}: N={n} NB={nb} on {nranks} chips, "
                       f"factor wall {max(walls):.2f} s", flush=True)
                 reports = [_device_report(p[0]) for p in parts]
-                for r, (st, refused) in enumerate(reports):
+                for r, st in enumerate(reports):
                     print(f"{name} {what}: rank {r} on {devices[r]} "
-                          f"dev.stats {json.dumps(st)} "
-                          f"fuser refused {json.dumps(refused)}", flush=True)
+                          f"dev.stats {json.dumps(st)}", flush=True)
                 if rep == 1:
                     bad += _check(parts, n, name)
                 checked.wait()
@@ -263,13 +258,13 @@ def run_colocated_phase(name, n, nb, nranks):
                 t.join()
         if errors:
             return bad + errors
-        for r, (st, refused) in enumerate(reports):
+        for r, st in enumerate(reports):
             want = _panel_tasks_of_rank(nt, nranks, r)
             bad += [f"{what}: rank {r}: {b}" for b in _phase_failures(
-                st, refused, watch.lines if r == 0 else [], want)]
-        d2d = sum(st["dp_d2d_bytes"] for st, _ in reports)
+                st, watch.lines if r == 0 else [], want)]
+        d2d = sum(st["dp_d2d_bytes"] for st in reports)
         host = sum(st["dp_recv_bytes"] + st["h2d_bytes"] + st["d2h_bytes"]
-                   for st, _ in reports)
+                   for st in reports)
         print(f"{name} {what}: cross-chip dp_d2d_bytes {d2d}, host payload "
               f"bytes (dp_recv + h2d + d2h) {host}", flush=True)
         if d2d <= 0 or host:

@@ -17,8 +17,7 @@ from .flowgraph import (ConcreteGraph, FlowGraph, collection_tile_bytes,
 from .verify import (RULES, Finding, Report, VerifyError, verify_graph,
                      verify_taskpool)
 from .plan import (CostModel, Plan, PlanCheckError, certify_waves,
-                   chain_certificates, compare_critpath, plan_graph,
-                   plan_taskpool)
+                   compare_critpath, plan_graph, plan_taskpool)
 from .tune import (ScheduleSimulator, TuneStore, apply_knobs, autotune,
                    graph_signature, hold_knobs, host_fingerprint)
 from .control import Controller, SimClock
@@ -30,7 +29,7 @@ __all__ = [
     "Finding", "Report", "RULES", "VerifyError", "verify_graph",
     "verify_taskpool",
     "CostModel", "Plan", "PlanCheckError", "plan_graph", "plan_taskpool",
-    "compare_critpath", "certify_waves", "chain_certificates",
+    "compare_critpath", "certify_waves",
     "ScheduleSimulator", "TuneStore", "apply_knobs", "hold_knobs",
     "autotune", "graph_signature", "host_fingerprint",
     "Controller", "SimClock",

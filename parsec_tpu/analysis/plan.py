@@ -188,17 +188,6 @@ class Plan:
         # the MPK-prep artifact (ROADMAP item 1): an explicit
         # certify/refuse verdict for every wave, machine-readable
         self.fusability: List[dict] = []
-        # wave-chain certificates: one record per adjacent pair of
-        # certified waves — `linked` proves the producer wave feeds the
-        # consumer wave rank-locally with matching tile signatures, so
-        # the device wave compiler (device/fuse.py) may compile both
-        # into ONE multi-wave executable; refusals carry reasons
-        self.chains: List[dict] = []
-        # rank -> (producer cls, params) -> [consumer link dicts]; the
-        # runtime consumption side of the chain certificates (see
-        # chain_index())
-        self._chain_links: Dict[int, Dict[tuple, list]] = {}
-        self._chain_classes: Dict[str, dict] = {}
         self.makespan: Dict[str, object] = {}
         self.eager_limit = 0
         self.has_device_classes = False
@@ -283,35 +272,6 @@ class Plan:
         signatures."""
         return sum(1 for c in self.fusability
                    if c["fusable"] and (rank is None or c["rank"] == rank))
-
-    def chained_waves(self, rank: Optional[int] = None) -> int:
-        """Number of certified chain LINKS (adjacent wave pairs the
-        device wave compiler may fuse into one multi-wave executable)."""
-        return sum(1 for c in self.chains
-                   if c["linked"] and (rank is None or c["rank"] == rank))
-
-    def chain_index(self, rank: int = 0) -> dict:
-        """Certificate-consumption view for the device wave compiler:
-
-          classes  {cls_name: {"id", "param_slots"}} — param_slots are
-                   the native local-variable indices whose values form
-                   the instance key (the same tuple order the
-                   concretized graph uses), so the device can key a
-                   LIVE task to its certificate lane with a handful of
-                   ptc_task_local reads
-          links    {(producer cls, params): [consumer dicts]} for this
-                   rank; each consumer dict carries its class, params
-                   and per-read-flow input spec:
-                     ("wave", producer_params, producer_flow) — comes
-                        from the producer wave's output (in-program)
-                     ("mem", collection, idx) — an external collection
-                        tile, fetchable at speculation time
-
-        Every spec is STATIC; the runtime re-validates all of it
-        against live copy versions at consumption, so a stale index can
-        only cost a wasted speculation, never a wrong answer."""
-        return {"classes": dict(self._chain_classes),
-                "links": dict(self._chain_links.get(rank, {}))}
 
     def wire_out_bound(self, rank: int,
                        cls: Optional[str] = None) -> int:
@@ -588,8 +548,6 @@ class Plan:
                       for r, ws in self.waves.items()},
             "fusability": [dict(c) for c in self.fusability],
             "fusable_waves": self.fusable_waves(),
-            "chains": [dict(c) for c in self.chains],
-            "chained_waves": self.chained_waves(),
             "makespan": dict(self.makespan),
             "comm": {
                 "total_bytes": self.comm_bytes(),
@@ -653,12 +611,6 @@ class Plan:
                 f"  fusable waves: {nfus}/{len(self.fusability)} "
                 "certified (homogeneous, independent, table-driven "
                 "bodies, one tile signature)")
-        if self.chains:
-            lines.append(
-                f"  chained waves: {self.chained_waves()}/"
-                f"{len(self.chains)} adjacent certified pairs linked "
-                "(producer wave feeds consumer wave rank-locally, "
-                "matching tile signatures — multi-wave fusable)")
         m = self.makespan
         if m:
             lines.append(
@@ -978,7 +930,6 @@ class _Analyzer:
             plan.waves[r] = rows
 
         plan.fusability = self.certify()
-        plan.chains = self.certify_chains(plan.fusability)
         fus = {(c["rank"], c["wave"]): c for c in plan.fusability}
         for r, rows in plan.waves.items():
             for row in rows:
@@ -1030,7 +981,6 @@ class _Analyzer:
         for node in self.inst_set:
             members.setdefault(
                 (self._rank(node), self.wave[node]), []).append(node)
-        self.members = members  # reused by the chain pass
         certs: List[dict] = []
         for (r, w) in sorted(members):
             nodes = sorted(members[(r, w)])
@@ -1042,7 +992,7 @@ class _Analyzer:
                 cert = {"rank": r, "wave": w, "cls": None,
                         "width": len(nodes), "homogeneous": False,
                         "claimed": False, "fusable": False,
-                        "body_kinds": [], "chain_next": False,
+                        "body_kinds": [],
                         "reasons": [f"heterogeneous wave "
                                     f"({', '.join(names)})"]}
                 certs.append(cert)
@@ -1110,156 +1060,10 @@ class _Analyzer:
                 "fusable": claimed and not reasons,
                 "body_kinds": kinds,
                 "tile_sig": sorted(sigs)[0] if len(sigs) == 1 else None,
-                "chain_next": False,
                 "reasons": reasons,
                 "structural": bool(structural),
             })
         return certs
-
-    # ------------------------------------------------------ wave chains
-    def certify_chains(self, certs: List[dict]) -> List[dict]:
-        """Chain certificates: one record per ADJACENT pair of
-        individually-certified waves on one rank, proving (or refusing,
-        with reasons — never silently) that the pair may compile into a
-        single multi-wave executable (the MPK one-level-up step,
-        arXiv:2512.22219):
-
-          tile shapes   both waves share one tile signature (one
-                        executable = one buffer shape set)
-          locality      no certain producer->consumer edge of the pair
-                        crosses ranks (a cross-rank edge means the
-                        consumer wave cannot complete from locally
-                        parked results)
-          resolvable    every consumer read flow is either fed by a
-                        single certain producer inside the producer
-                        wave (in-program dataflow) or is a statically
-                        evaluable collection read (fetchable at
-                        speculation time); anything else — maybe-edges,
-                        multi-source selection, arena-fresh inputs,
-                        nonadjacent task sources — refuses
-
-        A `linked` pair feeds Plan.chain_index(): the runtime
-        re-validates every input against live copy versions at
-        consumption, so these records can only cost a wasted
-        speculation when stale, never a wrong answer."""
-        fg, cg = self.fg, self.cg
-        plan = self.plan
-        by_rw = {(c["rank"], c["wave"]): c for c in certs}
-        chains: List[dict] = []
-        classes_used: Dict[str, dict] = {}
-
-        def _use_class(cm):
-            classes_used[cm.name] = {"id": cm.id,
-                                     "param_slots": list(cm.range_slots)}
-
-        for (r, w) in sorted(by_rw):
-            cert = by_rw[(r, w)]
-            nxt = by_rw.get((r, w + 1))
-            if not cert.get("fusable") or nxt is None \
-                    or not nxt.get("fusable"):
-                continue  # only certified pairs get a chain verdict
-            rec = {"rank": r, "wave": w, "next_wave": w + 1,
-                   "cls": cert["cls"], "next_cls": nxt["cls"],
-                   "width": cert["width"], "next_width": nxt["width"],
-                   "linked": False, "reasons": []}
-            chains.append(rec)
-            if cert.get("tile_sig") != nxt.get("tile_sig"):
-                rec["reasons"].append(
-                    "tile-signature mismatch across the pair (one "
-                    "executable needs one buffer shape set)")
-                continue
-            prod_nodes = set(self.members.get((r, w), ()))
-            cons_nodes = self.members.get((r, w + 1), [])
-            # locality: certain edges into the consumer wave must stay
-            # on this rank (both directions of the pair)
-            cross = 0
-            for n1 in prod_nodes:
-                for dst, certain in cg.succ.get(n1, ()):
-                    if certain and self.wave.get(dst) == w + 1 \
-                            and self._rank(dst) != r:
-                        cross += 1
-            if cross:
-                rec["reasons"].append(
-                    f"{cross} cross-rank producer->consumer edge(s)")
-                continue
-            lane_links: Dict[tuple, list] = {}
-            fed = 0
-            for n2 in cons_nodes:
-                cm2 = fg.classes[n2[0]]
-                l2 = self.locals_of(n2)
-                ins: List[tuple] = []
-                srcs: List[tuple] = []
-                why = None
-                for fi, fl in enumerate(cm2.flows):
-                    if fl.access not in (N.FLOW_READ, N.FLOW_RW):
-                        continue
-                    di = cg.selected.get((n2, fi))
-                    if di is None:
-                        why = (f"{cm2.name} flow {fl.name}: no "
-                               "statically resolvable input source")
-                        break
-                    info = cm2._dep_info[(fi, di)]
-                    if info["kind"] == "mem":
-                        try:
-                            idx = tuple(fn(l2) for fn in info["idx"])
-                        except Exception:
-                            why = (f"{cm2.name} flow {fl.name}: "
-                                   "collection index not evaluable")
-                            break
-                        ins.append((fl.name,
-                                    ("mem", info["coll"], idx)))
-                        continue
-                    if info["kind"] != "task":
-                        why = (f"{cm2.name} flow {fl.name}: "
-                               "arena-fresh input (no producer)")
-                        break
-                    key = (n2, fi)
-                    if cg.nmaybe.get(key, 0) \
-                            or cg.ncert.get(key, 0) != 1 \
-                            or not cg.src_sample.get(key):
-                        why = (f"{cm2.name} flow {fl.name}: input "
-                               "source not a single certain edge")
-                        break
-                    src, (pcid, pfi, _pdi), _c = cg.src_sample[key][0]
-                    if src not in prod_nodes:
-                        why = (f"{cm2.name} flow {fl.name}: producer "
-                               f"{cg.node_name(src)} is not in the "
-                               "adjacent wave")
-                        break
-                    pname = fg.classes[pcid].flows[pfi].name
-                    ins.append((fl.name, ("wave", src[1], pname)))
-                    srcs.append(src)
-                if why is not None:
-                    rec["reasons"].append(why)
-                    lane_links = {}
-                    break
-                if not srcs:
-                    rec["reasons"].append(
-                        f"{cg.node_name(n2)} reads nothing from the "
-                        "producer wave")
-                    lane_links = {}
-                    break
-                fed += 1
-                entry = {"cls": cm2.name, "params": n2[1], "ins": ins}
-                _use_class(cm2)
-                for src in sorted(set(srcs)):
-                    _use_class(fg.classes[src[0]])
-                    lane_links.setdefault(
-                        (fg.classes[src[0]].name, src[1]),
-                        []).append(entry)
-            if not lane_links or fed != len(cons_nodes):
-                if not rec["reasons"]:
-                    rec["reasons"].append("no consumer resolved")
-                continue
-            rec["linked"] = True
-            cert["chain_next"] = True
-            rlinks = plan._chain_links.setdefault(r, {})
-            for key, entries in lane_links.items():
-                # a producer key never spans two wave pairs (waves
-                # partition instances), so plain insert is safe
-                rlinks.setdefault(key, []).extend(entries)
-        plan._chain_classes.update(classes_used)
-        return chains
 
     # ---------------------------------------------------------- comm
     def _comm_volume(self, eager_limit: int):
@@ -1487,31 +1291,6 @@ def certify_waves(fg: FlowGraph, cg: ConcreteGraph) -> List[dict]:
     an = _Analyzer(fg, cg, plan)
     an.compute_waves()
     return an.certify()
-
-
-def chain_certificates(tp, max_instances: Optional[int] = None
-                       ) -> Optional[Plan]:
-    """Wave + chain certification only — the device wave compiler's
-    certificate-consumption entry point (no cost model, economics or
-    comm analysis: a fraction of a full plan).  Returns a Plan whose
-    `fusability`, `chains` and `chain_index()` are populated, or None
-    when concrete enumeration was refused (the compiler then refuses
-    fusion with an explicit reason, never a silent guess)."""
-    from .flowgraph import extract_flowgraph
-    if max_instances is None:
-        from ..utils import params as _mca
-        max_instances = int(_mca.get("plan.max_instances"))
-    fg = extract_flowgraph(tp)
-    cg = fg.concretize(max_instances=max_instances)
-    if cg.bounded:
-        return None
-    plan = Plan(fg)
-    plan.cg = cg
-    an = _Analyzer(fg, cg, plan)
-    an.compute_waves()
-    plan.fusability = an.certify()
-    plan.chains = an.certify_chains(plan.fusability)
-    return plan
 
 
 # ---------------------------------------------------------------- driver

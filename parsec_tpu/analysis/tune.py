@@ -73,7 +73,6 @@ TUNE_KNOBS: Tuple[str, ...] = (
     "coll.max_slices",      # slices per collective segment
     "device.staging_slots", # prefetch double-buffering depth
     "device.cache_bytes",   # device byte budget (0 = constructor default)
-    "device.wave_fuse",     # wave mega-kernelization (ptc-fuse)
     "runtime.mag_batch",    # task/arena freelist magazine batch
     # ptc-topo: per-link-class overrides ("" = inherit the base knob).
     # The simulator prices each cross-rank edge at ITS class, so these
@@ -454,11 +453,10 @@ class ScheduleSimulator:
             cm = fg.classes[n[0]]
             self.exec_ns[n] = float(self.cost.ns(cm.name))
             self.is_dev[n] = n[0] in dev_cls
-        # ptc-fuse pricing input: nodes sitting in a CERTIFIED fusable
-        # wave (plan.certify) share ONE dispatch-overhead charge when
-        # the wave_fuse knob is on — the simulator's model of the wave
-        # compiler collapsing a wave into one launch.  Only device
-        # nodes qualify (fusion lives in the device layer).
+        # nodes sitting in a CERTIFIED fusable wave (plan.certify)
+        # share ONE dispatch-overhead charge: the device manager runs a
+        # batched wave as one launch.  Only device nodes qualify
+        # (batching lives in the device layer).
         self.fused_width: Dict[tuple, int] = {}
         cert_w = {(c["rank"], c["wave"]): c["width"]
                   for c in self.plan.fusability
@@ -605,7 +603,6 @@ class ScheduleSimulator:
         mag = max(1, int(kv["runtime.mag_batch"]))
         slots = max(1, int(kv["device.staging_slots"]))
         cache = int(kv["device.cache_bytes"] or 0)
-        wave_fuse = bool(kv.get("device.wave_fuse", True))
         dispatch = DISPATCH_BASE_NS + DISPATCH_MAG_NS / mag
 
         indeg = dict(self.indeg0)
@@ -634,15 +631,11 @@ class ScheduleSimulator:
                 # the previous wave's compute — the dispatch stalls for
                 # the task's staged input volume
                 stall = self.in_bytes.get(n, 0) * H2D_BYTE_NS
-            disp_n = dispatch
-            if wave_fuse:
-                # certified fusable wave -> ONE launch for the whole
-                # wave: the per-task share of the dispatch overhead is
-                # 1/width (ptc-fuse; the certificate is the gate, so
-                # uncertified waves keep the full per-task charge)
-                fw = self.fused_width.get(n)
-                if fw:
-                    disp_n = dispatch / fw
+            # certified fusable wave -> ONE launch for the whole wave:
+            # the per-task share of the dispatch overhead is 1/width
+            # (the certificate is the gate, so uncertified waves keep
+            # the full per-task charge)
+            disp_n = dispatch / self.fused_width.get(n, 1)
             dur = self.exec_ns[n] + disp_n + stall
             finish = start + dur
             stall_total += stall
@@ -750,12 +743,6 @@ class ScheduleSimulator:
         else:
             axes["device.staging_slots"] = [kv["device.staging_slots"]]
             axes["device.cache_bytes"] = [kv["device.cache_bytes"]]
-        if self.has_device and self.fused_width:
-            # fusion width vs staging: only worth searching when a
-            # certified fusable wave exists for the compiler to fuse
-            axes["device.wave_fuse"] = [True, False]
-        else:
-            axes["device.wave_fuse"] = [kv["device.wave_fuse"]]
         return axes
 
     # ------------------------------------------------------- search

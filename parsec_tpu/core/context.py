@@ -1039,9 +1039,7 @@ class Context:
         """Wave-granular ready-front census (native
         ptc_peek_ready_front): [(class_id, taskpool_ptr), ...] for the
         tasks still queued on `qid` — class ids only, nothing popped or
-        pinned.  The wave compiler uses it to see whether the remainder
-        of a certified wave is already queued before fusing a
-        partially-popped front; DTD tasks report class_id -1."""
+        pinned.  DTD tasks report class_id -1."""
         buf = (C.c_int64 * (2 * max_tasks))()
         n = N.lib.ptc_peek_ready_front(self._ptr, qid, buf, max_tasks)
         return [(buf[2 * i], buf[2 * i + 1]) for i in range(n)]
@@ -1069,24 +1067,6 @@ class Context:
         moved = agg["prefetch_h2d_ns"] + agg["h2d_stall_ns"]
         agg["overlap_ratio"] = (
             round(agg["prefetch_h2d_ns"] / moved, 4) if moved else 0.0)
-        # ptc-fuse wave-compiler counters, aggregated across devices;
-        # `refused` merges the per-reason refusal records (the runtime
-        # mirror of certify()'s refuse records — no silent fallback)
-        fuse_keys = ("fused_waves", "fused_tasks", "fused_chains",
-                     "chain_waves", "chain_parked", "chain_hits",
-                     "chain_misses", "chain_drops", "cache_hits",
-                     "cache_misses", "parked")
-        fuse = {k: sum(d.get("fuse", {}).get(k, 0) for d in devs)
-                for k in fuse_keys}
-        fuse["enabled"] = any(d.get("fuse", {}).get("enabled")
-                              for d in devs)
-        refused: Dict[str, int] = {}
-        for d in devs:
-            for reason, n in d.get("fuse", {}).get("refused",
-                                                   {}).items():
-                refused[reason] = refused.get(reason, 0) + n
-        fuse["refused"] = refused
-        agg["fuse"] = fuse
         agg["devices"] = devs
         return agg
 

@@ -97,79 +97,18 @@ class _PrefetchLane:
 
         while not self._stop.is_set():
             try:
-                hinted = self._stage_hints()
                 if N.lib.ptc_device_queue_depth(ctx._ptr, dev.qid) <= 0:
                     if dev._pf_pin:
                         with dev._lock:
                             dev._pf_pin = set()
-                    if not hinted:
-                        wait(0.001)
+                    wait(0.001)
                     continue
-                if not self._sweep() and not hinted:
+                if not self._sweep():
                     wait(0.0005)
             except Exception:
                 import traceback
                 traceback.print_exc()
                 time.sleep(0.01)
-
-    def _stage_hints(self) -> bool:
-        """Stage the wave compiler's chain hints: external collection
-        tiles the NEXT certified chain segment will read (fuse.py
-        publishes them at each chain dispatch).  Unlike the peeked
-        lookahead these tiles belong to tasks the runtime has not
-        released yet, so there is no copy to pin — instead each stage
-        is version-stamped from the collection's host copy and the
-        consumer's stage-in only uses a mirror whose version still
-        matches (a tile written in between simply wastes the stage).
-        Collection host buffers are user Data: they outlive the pool,
-        so reading them unpinned is safe."""
-        dev = self.dev
-        hints, dev._pf_chain_hints = dev._pf_chain_hints, []
-        if not hints:
-            return False
-        ctx = dev.ctx
-        staged = False
-        for coll_name, idx in hints:
-            if self._stop.is_set():
-                break
-            try:
-                coll = getattr(ctx, "collection_objs", {}).get(coll_name)
-                if coll is None or not hasattr(coll, "data_of"):
-                    continue
-                d = coll.data_of(*idx)
-                cptr = N.lib.ptc_data_host_copy(d._ptr)
-                uid = dev._copy_uid(cptr)
-                ver = N.lib.ptc_copy_version(cptr)
-                q, v = ctx.device_get_data_owner(uid)
-                if q >= 0 and v == ver:
-                    continue  # a current mirror already serves it
-                with dev._lock:
-                    if uid in dev._cache:
-                        continue
-                tile = np.ascontiguousarray(coll.tile(*idx))
-                size = int(tile.nbytes)
-                if not dev._prefetch_reserve(size):
-                    continue
-                try:
-                    raw = tile.reshape(-1).view(np.uint8).copy()
-                    t0 = time.perf_counter_ns()
-                    N.lib.ptc_prof_event(ctx._ptr, KEY_H2D, 0, -1,
-                                         size, dev.qid, 1)
-                    darr = dev._jax.device_put(raw, dev.device)
-                    N.lib.ptc_prof_event(ctx._ptr, KEY_H2D, 1, -1,
-                                         size, dev.qid, 1)
-                    dev._stats_add("prefetch_h2d_ns",
-                                   time.perf_counter_ns() - t0)
-                except Exception:
-                    dev._prefetch_unreserve(size)
-                    raise
-                if dev._cache_put_prefetch(uid, ver, darr, size):
-                    dev._stats_add("h2d_bytes", size)
-                    staged = True
-            except Exception:
-                import traceback
-                traceback.print_exc()
-        return staged
 
     def _free_slots(self) -> int:
         """Recycle slots whose every tile was consumed or dropped."""

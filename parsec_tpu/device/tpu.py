@@ -683,8 +683,8 @@ class _Epilogue:
 
 def _single_stack(ents):
     """(stack, row_idxs) when every entry is a lazy slice of ONE source
-    stack — the gather can then ride inside the fused program — else
-    None.  Shared by fold_stack and the fused dispatcher so
+    stack — the gather can then ride inside the wave program — else
+    None.  Shared by fold_stack and `TpuDevice._fold` so
     padding/identity semantics cannot diverge."""
     if not ents or not all(isinstance(e, _StackRef) for e in ents):
         return None
@@ -892,6 +892,9 @@ class _CacheEnt:
 class TpuDevice:
     """One TPU device (one jax device) with a manager thread."""
 
+    # no wave compiler: benchmark/harness.py still reads it for check (b)
+    _fuser = None
+
     def __init__(self, ctx: Context, jax_device=None, pipeline_depth: int = 16,
                  cache_bytes: Optional[int] = None, autostart: bool = True,
                  prefetch: Optional[bool] = None):
@@ -970,29 +973,8 @@ class TpuDevice:
         # so the bench can tell prefetch-hit waves (aux == 0) from
         # staged ones without a second event
         self._disp_stall_ns = 0
-        # fused-dispatch mark for the NEXT DEVICE span's begin aux
-        # (manager thread only): 0 plain, n >= 1 a certified wave
-        # executable covering n wave(s) — set by the wave compiler
-        self._disp_fused = 0
-        # HBM pinned by parked chain speculations (ptc-fuse): the
-        # output stacks of speculated waves live outside the cache
-        # accounting until their tasks consume them, so the wave
-        # compiler charges them here and refuses to chain under
-        # residency pressure — out-of-core pools keep the PR 12
-        # spill behavior instead of pinning unaccounted stacks
-        self._chain_pinned = 0
         # keys of the eager-gather programs built (stats gather_shapes)
         self._gather_keys: set = set()
-        # ptc-fuse wave compiler (device.wave_fuse knob; None = off
-        # reproduces the per-group batched dispatch path bit-exactly)
-        self._fuser = None
-        if bool(_mca.get("device.wave_fuse")):
-            from .fuse import WaveFuser
-            self._fuser = WaveFuser(self)
-        # chain prefetch hints: [(collection name, idx tuple)] the wave
-        # compiler predicts the NEXT chain segment will read; the
-        # prefetch lane stages them alongside the peeked lookahead
-        self._pf_chain_hints: list = []
         self._dbg(f"device up: {self.device} queue={self.qid} "
                   f"cache={cache_bytes >> 20}MiB batch<= {self.batch_max}")
         self._stop = threading.Event()
@@ -1498,7 +1480,6 @@ class TpuDevice:
             # keys lazily, and dict iteration during an insert raises
             stats = dict(self.stats)
             attached = len(self.bodies)
-        fz = self._fuser
         return {
             "device": str(self.device),
             "kind": getattr(self.device, "device_kind", "?"),
@@ -1512,15 +1493,6 @@ class TpuDevice:
             # instances of one client), hence the name
             "process_jit_kernels": len(_JIT_CACHE),
             "stats": stats,
-            # ptc-fuse wave-compiler counters (schema-stable when off)
-            "fuse": (fz.snapshot() if fz is not None
-                     else {"enabled": False, "fused_waves": 0,
-                           "fused_tasks": 0, "fused_chains": 0,
-                           "chain_waves": 0, "chain_parked": 0,
-                           "chain_hits": 0, "chain_misses": 0,
-                           "chain_drops": 0, "cache_hits": 0,
-                           "cache_misses": 0, "parked": 0,
-                           "refused": {}}),
         }
 
     def _dbg(self, msg: str):
@@ -1898,8 +1870,6 @@ class TpuDevice:
             self._stacks.clear()
             self._spec.clear()
             self._cache_used = 0
-        if self._fuser is not None:
-            self._fuser.clear()
         if err is not None:
             raise err
 
@@ -2022,24 +1992,10 @@ class TpuDevice:
             key = id(body)
             gi = index.get(key)
             if gi is None or body is None or not body.batch:
-                if gi is not None and body is not None \
-                        and not body.batch \
-                        and self._fuser is not None:
-                    # >= 2 ready tasks of a vmap-incompatible
-                    # class: the wave exists but cannot fuse —
-                    # recorded, mirroring certify()'s opaque-body
-                    # refusals (no silent fallback)
-                    self._fuser._refuse("unbatchable-body")
                 index[key] = len(groups)
                 groups.append((body, [t]))
             else:
                 groups[gi][1].append(t)
-        if self._fuser is not None and len(
-                {id(b) for b, _ in groups if b is not None}) > 1:
-            # mixed ready front: each group still certifies on its
-            # own, but the front as popped was not ONE wave —
-            # recorded like certify()'s heterogeneous refusals
-            self._fuser._refuse("heterogeneous-front")
         for body, ts in groups:
             if body is None:
                 for t in ts:
@@ -2275,34 +2231,22 @@ class TpuDevice:
         event's aux carries the wave's dispatch-time h2d stall in ns
         (0 == every input was resident/prefetched: a prefetch-hit
         wave), so the bench reads staged-vs-prefetched latency straight
-        off paired spans.  The BEGIN event's aux marks FUSED dispatches
-        (ptc-fuse): 0 = plain, n >= 1 = a certified wave executable
-        covering n wave(s) — the bench-device fused-vs-unfused section
-        counts launches straight off these spans."""
+        off paired spans.  The BEGIN event's aux is 0."""
         cid = body.tc.id if body.tc is not None else -1
         if phase == 0:
             self._disp_stall_ns = 0
-            aux = self._disp_fused
+            aux = 0
         else:
             aux = self._disp_stall_ns
-            self._disp_fused = 0
         self._spans.event(KEY_DEVICE, phase, cid, lanes, self.qid, aux)
 
     def _dispatch_group_chunk(self, body: _DeviceBody, tasks: List):
-        fz = self._fuser
-        if fz is not None:
-            # ptc-fuse: parked chain results complete first (zero
-            # launches), then the wave compiler certifies the remainder
-            # online — a certified wave marks its DEVICE span, and a
-            # certified CHAIN dispatches entirely inside the compiler
-            tasks = fz.consume_group(body, tasks)
-            if not tasks:
-                return
-            if len(tasks) == 1:
-                self._dispatch_one(body, tasks[0])
-                return
-            if fz.dispatch_group(body, tasks):
-                return
+        if len(tasks) == 1:
+            # a byte-capped wave's one-lane remainder runs the
+            # single-task program lone pops already built, not a
+            # one-lane vmap of its own
+            self._dispatch_one(body, tasks[0])
+            return
         self._prof(0, body, len(tasks))
         try:
             self._dispatch_group_run(body, tasks)
@@ -2313,10 +2257,7 @@ class TpuDevice:
         """Fused-gather marshaling for one wave: per read flow, decide
         how the lanes' inputs enter the jitted program (in-program
         gather / shared broadcast / pre-stacked) and build the flat
-        call args.  Shared by the batched group dispatch and the wave
-        compiler (fuse.py) so the two can never marshal differently —
-        the chain executable's level 0 IS the group dispatch's
-        program."""
+        call args for the batched group dispatch."""
         sig, call_args = [], []
         for f in body.reads:
             ents = self._flow_entries(views, body, f)
@@ -2466,9 +2407,6 @@ class TpuDevice:
             self.ctx.task_complete(t)
 
     def _dispatch_one(self, body, task):
-        fz = self._fuser
-        if fz is not None and fz.consume(body, task):
-            return  # completed from a parked chain result: no launch
         self._prof(0, body, 1)
         try:
             self._dispatch_one_run(body, task)

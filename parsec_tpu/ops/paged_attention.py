@@ -826,9 +826,9 @@ def build_paged_verify(ctx, pool: PagePool, seqs: Sequence[SeqSpec],
     per (real sequence, query position): the engine flattens a k-token
     draft window into k+1 of these, and the resulting VATF wave is
     HOMOGENEOUS — with a device attached it carries the same
-    shape-uniform chore as decode's PATTF, so the PR 13 wave compiler
-    certifies it and the whole batched verification dispatches as one
-    fused launch.  Fold math and page blocking are `attend_page` with
+    shape-uniform chore as decode's PATTF, so the device manager
+    dispatches the whole verification as one batched launch.  Fold
+    math and page blocking are `attend_page` with
     the decode builder's exact operand split: a verified position's
     output is BIT-IDENTICAL to the sequential decode step's.
 
@@ -869,10 +869,8 @@ def build_paged_verify(ctx, pool: PagePool, seqs: Sequence[SeqSpec],
     if dev is not None:
         # same shape-uniform fold as decode's PATTF, but declared
         # BATCHABLE (the kernel is elementwise over whole-page tiles):
-        # a homogeneous VATF wave certifies under the PR 13 wave
-        # compiler and the entire batched verification dispatches as
-        # ONE fused launch — in tp mode each rank certifies and fuses
-        # ITS OWN shard of the wave (the per-rank fused_waves count)
+        # a homogeneous VATF wave dispatches as ONE batched launch — in
+        # tp mode each rank batches ITS OWN shard of the wave
         def k_fold(qb, kb, vb, ab):
             if nh == 1:
                 return _fold_kernel(qb, kb, vb, ab, sc)

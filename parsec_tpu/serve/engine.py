@@ -881,7 +881,7 @@ class InferenceEngine:
         draft proposes up to k tokens per sequence, and the k+1 query
         positions (current token + each draft token) verify in ONE
         batched target-model wave (build_paged_verify — the VATF wave
-        is homogeneous, so PR 13 fuses it to a single launch).
+        is homogeneous, so a device runs it as one batched launch).
 
         Per (sequence, query i): the query window's pages — every page
         touched by rows L..L+i — are PRIVATE clones (existing rows

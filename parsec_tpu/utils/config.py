@@ -490,24 +490,6 @@ register("device.cache_bytes", 0, int,
          "budget instead of the 4 GiB constructor default (the "
          "ptc-tune cache-budget knob; TpuDevice.set_cache_budget "
          "still re-budgets a live device)")
-register("device.wave_fuse", True, bool,
-         "wave mega-kernelization (ptc-fuse): certified homogeneous "
-         "waves popped by the device manager dispatch through the wave "
-         "compiler — counted and span-marked — and waves the static "
-         "plan proves form a producer->consumer chain compile into ONE "
-         "multi-wave XLA executable (MPK, arXiv:2512.22219): downstream "
-         "waves' results are computed inside the same program and "
-         "parked, so their tasks complete with ZERO device launches "
-         "(every parked result is version-checked against the real "
-         "task's input copies at consumption — any mismatch falls back "
-         "to a normal dispatch).  0 reproduces the PR 12 per-group "
-         "batched dispatch bit-exactly")
-register("device.wave_fuse_depth", 8, int,
-         "max waves fused into one chained executable (the chain "
-         "segment length): each extra wave removes one XLA launch but "
-         "holds one more wave of output stacks live inside the "
-         "program; power-of-two wave-width padding keeps compiles "
-         "O(log W) per class either way")
 register("tune.cache_path", "", str,
          "persisted autotuning winners (analysis/tune.py TuneStore): "
          "JSON keyed by (graph signature, host fingerprint), applied "

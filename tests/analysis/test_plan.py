@@ -219,63 +219,3 @@ def test_predicted_vs_executed_critpath():
     assert cmp["executed_path_len"] > 0
     assert cmp["predicted_ns"] > 0 and cmp["executed_ns"] > 0
     assert cmp["ratio"] is not None and cmp["cost_source"] == "metrics"
-
-
-# ------------------------------------------------- chain certificates
-def test_gemm_chain_certificates_linked():
-    """ptc-fuse prerequisite: on the single-rank GEMM every adjacent
-    pair of certified waves links (the C k-chain feeds lane-to-lane,
-    A/B are statically-known collection reads), and the consumption
-    index resolves a producer lane to its consumer with per-flow
-    specs."""
-    with pt.Context(nb_workers=1) as ctx:
-        _A, _B, _C, tp = _gemm(ctx, k=64)  # kt = 4 waves
-        plan = plan_taskpool(tp)
-    kt = 4
-    assert plan.fusable_waves() == kt
-    assert len(plan.chains) == kt - 1
-    assert plan.chained_waves() == kt - 1
-    assert all(c["linked"] and not c["reasons"] for c in plan.chains)
-    # certify records carry the chain flag
-    flagged = [c for c in plan.fusability if c.get("chain_next")]
-    assert len(flagged) == kt - 1
-    idx = plan.chain_index(0)
-    assert idx["classes"]["Gemm"]["param_slots"] == [0, 1, 2]
-    link = idx["links"][("Gemm", (0, 0, 0))]
-    assert len(link) == 1 and link[0]["cls"] == "Gemm"
-    assert link[0]["params"] == (0, 0, 1)
-    specs = dict(link[0]["ins"])
-    assert specs["C"] == ("wave", (0, 0, 0), "C")
-    assert specs["A"][0] == "mem" and specs["B"][0] == "mem"
-    # json rendering carries the chain records
-    doc = plan.to_json()
-    assert doc["chained_waves"] == kt - 1
-    assert len(doc["chains"]) == kt - 1
-
-
-def test_chain_certificates_refuse_with_reasons():
-    """gemm_dist: the Gemm waves certify but their A/B inputs arrive
-    from reader-broadcast TASKS outside the adjacent wave, so chain
-    pairs refuse — with explicit reasons, never silently."""
-    with pt.Context(nb_workers=1) as ctx:
-        _A, _B, _C, tp = _gemm(ctx, k=64, dist=True, nodes=2)
-        plan = plan_taskpool(tp)
-    assert plan.fusable_waves() > 0
-    assert plan.chained_waves() == 0
-    refused = [c for c in plan.chains if not c["linked"]]
-    assert refused and all(c["reasons"] for c in refused)
-    assert not any(c.get("chain_next") for c in plan.fusability)
-
-
-def test_chain_certificates_deterministic():
-    """Two extractions of one graph produce identical chain records and
-    consumption indices (the wave compiler caches them per pool; a
-    nondeterministic index would make fusion decisions flap)."""
-    from parsec_tpu.analysis import chain_certificates
-    with pt.Context(nb_workers=1) as ctx:
-        _A, _B, _C, tp = _gemm(ctx, k=64)
-        p1 = chain_certificates(tp)
-        p2 = chain_certificates(tp)
-    assert p1.chains == p2.chains
-    assert p1.chain_index(0) == p2.chain_index(0)
-    assert p1.fusability == p2.fusability

@@ -75,26 +75,6 @@ def test_intree_fusability_verdicts_complete():
     assert sum(counts.values()) >= 30
 
 
-def test_intree_chain_verdicts_complete():
-    """PR 13 (ptc-fuse): every adjacent pair of certified waves carries
-    an explicit chain verdict — linked, or refused with reasons (the
-    multi-wave fusion prerequisite; silent skips are a baseline
-    violation).  The single-rank GEMM's k-chain links end to end;
-    gemm_dist's pairs refuse (task-sourced A/B panels)."""
-    chained = {}
-    for name, p in _all_plans():
-        for c in p.chains:
-            assert isinstance(c["linked"], bool), name
-            if not c["linked"]:
-                assert c["reasons"], f"{name}: chain refusal w/o reason"
-            else:
-                assert not c["reasons"]
-        chained[name] = p.chained_waves()
-    assert chained["gemm"] == 3          # kt=4 waves -> 3 linked pairs
-    assert chained["gemm_dist"] == 0     # reader-bcast inputs refuse
-    assert chained["potrf"] >= 1         # adjacent GEMM-update waves
-
-
 def test_potrf_bench_tiling_under_5s():
     dt_ms = plan_graphs.potrf_nt16_ms()
     assert dt_ms < plan_graphs.POTRF_NT16_BUDGET_S * 1e3, \

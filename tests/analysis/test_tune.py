@@ -53,12 +53,8 @@ def test_simulator_deterministic_and_monotone():
     assert big["makespan_ns"] < small["makespan_ns"]
 
 
-def test_simulator_wave_fuse_pricing():
-    """ptc-fuse satellite: a certified fusable device wave is charged
-    ONE dispatch overhead when the wave_fuse knob is on (per-task share
-    1/width), so the simulated makespan drops vs wave_fuse=0 — and both
-    prices are bit-deterministic.  The knob axis only opens when a
-    certified wave exists for the compiler to fuse."""
+def _fan_plan():
+    """Device chores in homogeneous waves of 8: every wave certifies."""
     with pt.Context(nb_workers=1) as ctx:
         ctx.register_arena("t", 64)
         tp = pt.Taskpool(ctx, globals={"NB": 7, "KT": 3})
@@ -73,32 +69,46 @@ def test_simulator_wave_fuse_pricing():
                        guard=(k < pt.G("KT"))),
                 arena="t")
         tc.body_device(0)
-        plan = tp.plan()
+        return tp.plan()
+
+
+def test_simulator_wave_fuse_pricing():
+    """A certified fusable device wave runs as one batched launch, so
+    each member is charged 1/width of the dispatch overhead.  On one
+    worker the makespan is exactly the sum of the charges, and it is
+    bit-deterministic."""
+    plan = _fan_plan()
     assert plan.fusable_waves() > 0
-    sim = ScheduleSimulator(plan, workers=2)
+    sim = ScheduleSimulator(plan, workers=1)
     assert sim.fused_width, "certified fusable device waves expected"
-    assert sim.knob_axes()["device.wave_fuse"] == [True, False]
-    on = sim.simulate({"device.wave_fuse": True})
-    off = sim.simulate({"device.wave_fuse": False})
-    assert on == sim.simulate({"device.wave_fuse": True})
-    assert off == sim.simulate({"device.wave_fuse": False})
-    assert on["makespan_ns"] < off["makespan_ns"]
+    assert set(sim.fused_width.values()) == {8}
+    res = sim.simulate()
+    assert res == sim.simulate()
+    disp = res["dispatch_ns_per_task"]
+    want = sum(sim.exec_ns[n] + disp / sim.fused_width.get(n, 1)
+               for n in sim.nodes)
+    assert res["makespan_ns"] == pytest.approx(want, rel=1e-12)
+    # the same graph charged per task costs more
+    flat = ScheduleSimulator(plan, workers=1)
+    flat.fused_width = {}
+    assert flat.simulate()["makespan_ns"] > res["makespan_ns"]
+    assert "device.wave_fuse" not in TUNE_KNOBS
+    assert "device.wave_fuse" not in sim.knob_axes()
 
 
 def test_simulator_wave_fuse_axis_closed_without_certificates():
-    """No device chores -> no wave to fuse -> the axis stays collapsed
-    at the incumbent value (the search space must not grow for graphs
-    the compiler cannot touch)."""
+    """No device chores -> no certified wave -> every task keeps the
+    full per-task dispatch charge: on one worker the makespan is the
+    sum of exec time plus one whole charge per task."""
     with pt.Context(nb_workers=1) as ctx:
         _A, tp = _potrf(ctx)
         plan = tp.plan()
-    sim = ScheduleSimulator(plan, workers=2)
+    sim = ScheduleSimulator(plan, workers=1)
     assert not sim.fused_width
-    axes = sim.knob_axes()
-    assert axes["device.wave_fuse"] == [default_knobs()["device.wave_fuse"]]
-    # pricing is inert: on == off when nothing is fusable
-    assert sim.simulate({"device.wave_fuse": True}) == \
-        sim.simulate({"device.wave_fuse": False})
+    res = sim.simulate()
+    want = sum(sim.exec_ns[n] + res["dispatch_ns_per_task"]
+               for n in sim.nodes)
+    assert res["makespan_ns"] == pytest.approx(want, rel=1e-12)
 
 
 def test_simulator_workers_scale_work_bound():

@@ -2164,76 +2164,6 @@ def gemm_dist_plan(rank: int, nodes: int, port: int, N: int = 256,
                         rtol=2e-3, atol=2e-3)
 
 
-def gemm_dist_wave_fuse(rank: int, nodes: int, port: int, N: int = 64,
-                        nb: int = 8):
-    """ptc-fuse bit-exactness matrix, distributed leg: the SAME 2-rank
-    GEMM runs with the wave compiler on and with device.wave_fuse=0
-    (one device per pass — the knob binds at device creation), and
-    every owned C tile must match BITWISE.  The fused pass must
-    certify waves (fused_waves > 0: gemm_dist records 4 fusable waves
-    in PLAN_graphs.json); chains legitimately refuse — the A/B panels
-    arrive from reader-broadcast tasks, not collection reads."""
-    pt, ctx = _mk_ctx(rank, nodes, port)
-    from parsec_tpu.algos.gemm import build_gemm_dist
-    from parsec_tpu.data.collections import TwoDimBlockCyclic
-    from parsec_tpu.device.tpu import TpuDevice
-    from parsec_tpu.utils import params as _mca
-
-    with ctx:
-        P = 2 if nodes % 2 == 0 else 1
-        Q = nodes // P
-        rng = np.random.default_rng(11)
-        a = rng.normal(size=(N, N)).astype(np.float32)
-        b = rng.normal(size=(N, N)).astype(np.float32)
-        c0 = rng.normal(size=(N, N)).astype(np.float32)
-        mk = lambda: TwoDimBlockCyclic(N, N, nb, nb, P=P, Q=Q,
-                                       nodes=nodes, myrank=rank,
-                                       dtype=np.float32)
-        outs = {}
-        for fuse, tag in ((True, "f"), (False, "u")):
-            _mca.set("device.wave_fuse", fuse)
-            try:
-                A, B, C = mk(), mk(), mk()
-                A.register(ctx, "A" + tag); A.from_dense(a)
-                B.register(ctx, "B" + tag); B.from_dense(b)
-                C.register(ctx, "C" + tag); C.from_dense(c0)
-                dev = TpuDevice(ctx)
-                dev.batch_wait_ms = 2.0
-                tp = build_gemm_dist(ctx, A, B, C, dev=dev,
-                                     names=("A" + tag, "B" + tag,
-                                            "C" + tag))
-                tp.run()
-                tp.wait()
-                ctx.comm_fence()
-                dev.flush()
-                # per-device snapshot: ctx.device_stats() would fold
-                # the previous pass's (stopped) device back in
-                st = dev.info()["fuse"]
-                # remote releases are invisible to the engine's
-                # in-flight count: no sweep may close on it here
-                assert dev.stats["sweep_quiesced"] == 0, dev.stats
-                dev.stop()
-                tiles = {}
-                nt = C.mt
-                for m in range(nt):
-                    for n in range(nt):
-                        if C.rank_of(m, n) == rank:
-                            tiles[(m, n)] = C.tile(m, n).tobytes()
-                outs[tag] = (tiles, st)
-            finally:
-                _mca.unset("device.wave_fuse")
-        tiles_f, st_f = outs["f"]
-        tiles_u, st_u = outs["u"]
-        assert st_f["enabled"] is True and st_f["fused_waves"] > 0, st_f
-        assert st_u["enabled"] is False and st_u["fused_waves"] == 0
-        assert set(tiles_f) == set(tiles_u)
-        for key in tiles_f:
-            assert tiles_f[key] == tiles_u[key], \
-                f"tile {key} differs fused vs unfused"
-        ctx.comm_fence()
-        ctx.comm_fini()
-
-
 # ------------------------------------------------------ page migration
 def _author_page(pool, key, seed, page, d):
     """Freeze one page whose bytes are a pure function of `seed` — the

@@ -125,18 +125,10 @@ def test_unified_stats_schema_single_rank():
                       "prefetch_wakeups", "overlap_ratio", "devices",
                       "cache_peak_bytes"):
                 assert k in s["device"], k
-            # PR 13 (ptc-fuse): wave-compiler counters + the refused-
-            # by-reason export mirroring certify()'s refuse records —
-            # schema-stable whether the knob is on or off
-            fuse = s["device"]["fuse"]
-            assert set(fuse) == {"enabled", "fused_waves",
-                                 "fused_tasks", "fused_chains",
-                                 "chain_waves", "chain_parked",
-                                 "chain_hits", "chain_misses",
-                                 "chain_drops", "cache_hits",
-                                 "cache_misses", "parked", "refused"}
-            assert isinstance(fuse["enabled"], bool)
-            assert isinstance(fuse["refused"], dict)
+            # no wave compiler: batched launches are per-device
+            # counters (`batches`, `batched_tasks` in each device's
+            # stats), with no aggregate block of their own
+            assert "fuse" not in s["device"]
             # PR 10: ptc-plan pre-run check namespace (device.plan_check)
             assert set(s["plan"]) == {"enabled", "checks", "over_budget",
                                       "predicted_spills",

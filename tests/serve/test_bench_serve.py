@@ -57,6 +57,6 @@ def test_bench_serve_schema():
         assert sp[k]["tokens_per_s"] > 0
     assert sp["k4"]["accept_rate"] == 1.0  # oracle self-draft
     vw = sp["verify_wave"]
-    assert vw["single_fused_launch"] is True
-    assert vw["fused_marked_launches"] > 0
-    assert vw["device_launches"] < vw["fused_tasks"]
+    assert vw["single_batched_launch"] is True
+    assert vw["batches"] > 0
+    assert vw["device_launches"] < vw["batched_tasks"]

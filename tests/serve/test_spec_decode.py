@@ -145,9 +145,9 @@ def test_spec_with_prefix_cache_composes():
 
 
 def test_spec_verify_wave_fuses_on_device():
-    """With a TpuDevice attached the homogeneous VATF verify wave rides
-    the PR 13 wave compiler: fused launches observed, tokens identical
-    and outputs allclose to the non-speculative device run (device
+    """With a TpuDevice attached the homogeneous VATF verify wave runs
+    as batched device launches (`batches` / `batched_tasks`), tokens
+    identical and outputs allclose to the non-speculative device run (device
     batched-kernel lane bytes are width-dependent, so the DEVICE path
     promises allclose — bit-exactness is the host fold path's
     contract, gated above)."""
@@ -165,7 +165,7 @@ def test_spec_verify_wave_fuses_on_device():
                                       dev=dev, spec_k=spec_k)
                 hs = [eng.submit(p, 6, "t") for p in prompts]
                 eng.run(timeout_s=180)
-                ds = ctx.device_stats()
+                ds = dict(dev.stats)
                 eng.close()
             finally:
                 dev.stop()
@@ -173,8 +173,8 @@ def test_spec_verify_wave_fuses_on_device():
 
     hs1, ds1 = run(3)
     hs0, _ = run(0)
-    assert ds1["fuse"]["fused_waves"] > 0, ds1["fuse"]
-    assert ds1["fuse"]["fused_tasks"] > ds1["fuse"]["fused_waves"]
+    assert ds1["batches"] > 0, ds1
+    assert ds1["batched_tasks"] > ds1["batches"], ds1
     for h1, h0 in zip(hs1, hs0):
         assert h1.state == h0.state == "done"
         assert h1.tokens == h0.tokens

@@ -59,18 +59,6 @@ CHECKS = [
      "higher", 0.15, True),
     ("BENCH_device.json", "out_of_core_gemm.correct", "equal", 0.0,
      False),
-    # ptc-fuse (PR 13): wave mega-kernelization launch economics —
-    # launches/task and the fused-vs-unfused launch ratio are
-    # trajectory rows (timing-sensitive: partial wave pops under
-    # oversubscription split launches, so the slack convention
-    # applies); the fused-vs-unfused bit-exactness verdict is a
-    # correctness flag, never relaxed
-    ("BENCH_device.json", "wave_fuse.launches_per_task", "lower", 0.50,
-     True),
-    ("BENCH_device.json", "wave_fuse.fused_vs_unfused_ratio", "higher",
-     0.35, True),
-    ("BENCH_device.json", "wave_fuse.bit_identical", "equal", 0.0,
-     False),
     # serving runtime (PR 9): hi-tenant p99 improvement over the no-QoS
     # control is timing (trajectory-guarded, oversubscription-slacked);
     # the in-document beats-control verdict and the continuous-vs-
@@ -91,8 +79,8 @@ CHECKS = [
     # k=4 speculative tokens/s are oversubscription-slacked timing
     # trajectory rows; warm-run and speculative bit-exactness vs the
     # cold / non-speculative baselines are equal-direction correctness
-    # flags — never relaxed — as are the fewer-prefill-waves and
-    # single-fused-verify-launch evidence verdicts
+    # flags — never relaxed — as is the fewer-prefill-waves evidence
+    # verdict
     ("BENCH_serve.json", "prefix.hit_rate", "higher", 0.50, True),
     ("BENCH_serve.json", "prefix.warm_tokens_per_s", "higher", 0.50,
      True),
@@ -101,8 +89,6 @@ CHECKS = [
      False),
     ("BENCH_serve.json", "spec.k4.tokens_per_s", "higher", 0.50, True),
     ("BENCH_serve.json", "spec.bit_identical", "equal", 0.0, False),
-    ("BENCH_serve.json", "spec.verify_wave.single_fused_launch",
-     "equal", 0.0, False),
     # ptc-route (PR 16): 2-replica fleet scaling and global prefix hit
     # rate are oversubscription-slacked timing trajectory rows (both
     # replicas timeshare one process's cores); the routed-vs-single
@@ -116,13 +102,11 @@ CHECKS = [
     ("BENCH_serve.json", "fleet.fleet_scrape_ms", "lower", 0.50, True),
     # ptc-shard (PR 18): 2-/4-rank tensor-parallel decode vs the
     # single-rank reference — bit_identical (tokens AND exact f32
-    # pre-logit bytes, prefix cache + speculative decoding live) and
-    # the fused_waves>0-on-every-rank verdict are equal-direction
-    # correctness flags, never relaxed; the tp4-vs-tp1 per-token wall
-    # ratio is a timing trajectory row, oversubscription-slacked (all
-    # ranks timeshare one host)
+    # pre-logit bytes, prefix cache + speculative decoding live) is an
+    # equal-direction correctness flag, never relaxed; the tp4-vs-tp1
+    # per-token wall ratio is a timing trajectory row,
+    # oversubscription-slacked (all ranks timeshare one host)
     ("BENCH_serve.json", "tp.bit_identical", "equal", 0.0, False),
-    ("BENCH_serve.json", "tp.all_ranks_fused", "equal", 0.0, False),
     ("BENCH_serve.json", "tp.tp4_vs_tp1_ms_per_token", "lower", 0.50,
      True),
     # ptc-tune (PR 12): autotuned-vs-default ratios on the dispatch

@@ -105,11 +105,10 @@ def main(argv=None):
         peak = plan.peak_bytes()
         status = ("clean" if not issues else "; ".join(issues))
         fus = plan.fusable_waves()
-        chained = plan.chained_waves()
         print(f"{name:24s} {status}  "
               f"[{plan.stats.get('instances', 0)} inst, "
               f"{plan.stats.get('waves', 0)} wave(s), "
-              f"{fus} fusable, {chained} chained, peak {peak} B, "
+              f"{fus} fusable, peak {peak} B, "
               f"{plan.stats.get('elapsed_ms', 0):.0f} ms]")
         if issues:
             dirty += 1
@@ -118,8 +117,6 @@ def main(argv=None):
             "instances": plan.stats.get("instances", 0),
             "waves": plan.stats.get("waves", 0),
             "fusable_waves": fus,
-            "chained_waves": chained,
-            "chain_pairs": len(plan.chains),
             "certified_waves": len(plan.fusability),
             "peak_bytes": peak,
             "est_bytes": plan.est_bytes(),

@@ -932,103 +932,20 @@ def serve_churn(workers: int, port: int, pools_per_tenant: int = 24,
                 os.environ[k] = v
 
 
-def wave_fuse_gemm(workers: int, port: int, N: int = 32, nb: int = 8,
-                   env=None) -> None:
-    """ptc-fuse under TSan: two colocated ranks (a thread per rank) run
-    a distributed GEMM with the WAVE COMPILER ON over the streamed wire
-    — the fuse cache and online certification on each device manager
-    thread, the prefetch lane's peeks/hint staging, and the comm
-    threads' deliveries all race in one TSan-observed address space.
-    The chain path legitimately refuses on gemm_dist (task-sourced
-    panels); the certification + counter paths are what this job
-    drives concurrently with wire deliveries."""
-    import threading
-
-    env = dict(env or {})
-    env.setdefault("PTC_MCA_device_wave_fuse", "1")
-    saved = {k: os.environ.get(k) for k in env}
-    os.environ.update(env)
-    errs = []
-
-    def rank_prog(rank):
-        try:
-            import jax
-            jax.config.update("jax_platforms", "cpu")
-            from parsec_tpu.algos.gemm import build_gemm_dist
-            from parsec_tpu.data.collections import TwoDimBlockCyclic
-            from parsec_tpu.device.tpu import TpuDevice
-
-            ctx = pt.Context(nb_workers=workers, scheduler="lws")
-            ctx.set_rank(rank, 2)
-            ctx.comm_init(port)
-            with ctx:
-                rng = np.random.default_rng(3)
-                a = rng.normal(size=(N, N)).astype(np.float32)
-                b = rng.normal(size=(N, N)).astype(np.float32)
-                c0 = rng.normal(size=(N, N)).astype(np.float32)
-                mk = lambda: TwoDimBlockCyclic(
-                    N, N, nb, nb, P=2, Q=1, nodes=2, myrank=rank,
-                    dtype=np.float32)
-                A, B, C = mk(), mk(), mk()
-                A.register(ctx, "A"); A.from_dense(a)
-                B.register(ctx, "B"); B.from_dense(b)
-                C.register(ctx, "C"); C.from_dense(c0)
-                dev = TpuDevice(ctx)
-                tp = build_gemm_dist(ctx, A, B, C, dev=dev)
-                tp.run()
-                tp.wait()
-                ctx.comm_fence()
-                dev.flush()
-                ref = c0.astype(np.float64) + a.astype(np.float64) \
-                    @ b.astype(np.float64)
-                nt = C.mt
-                for m in range(nt):
-                    for n in range(nt):
-                        if C.rank_of(m, n) != rank:
-                            continue
-                        lo = np.abs(
-                            C.tile(m, n)
-                            - ref[m * nb:(m + 1) * nb,
-                                  n * nb:(n + 1) * nb]).max()
-                        assert lo < 2e-3, (m, n, lo)
-                dev.stop()
-                ctx.comm_fence()
-                ctx.comm_fini()
-        except Exception as e:  # pragma: no cover - stress harness
-            errs.append((rank, repr(e)))
-
-    try:
-        ts = [threading.Thread(target=rank_prog, args=(r,))
-              for r in range(2)]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join(timeout=600)
-        hung = [t.name for t in ts if t.is_alive()]
-        assert not hung, f"deadlocked rank threads: {hung}"
-        assert not errs, errs
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-
-
 def tp_decode_churn(workers: int, port: int, max_new: int = 5,
                     env=None) -> None:
     """ptc-shard (PR 18): TWO colocated ranks serve ONE tensor-parallel
     PagedLM — qkv/ffn rows and KV pages sharded by head (one PagePool
     per rank), every prefill/decode/verify pool embedding a RefReduce
     ptc_coll_* chain whose slice-granular step deliveries race the
-    wave compiler (per-rank shard-wave certification + fused dispatch
-    on the device manager thread) and the prefetch lane's peeks, all
+    batched dispatch on the device manager thread and the prefetch
+    lane's peeks, all
     over the streamed (rendezvous + chunked, 2-rail) wire.  A reader
     thread per rank concurrently scrapes the head-sharded pool
     counters, stats()["serve"]["tp"] (the coll_wait fold readers) and
     device_stats() while the SPMD step loop and both comm threads
     mutate them in one TSan-observed address space.  A final bitwise
-    check against the single-rank reference and the fused_waves>0 /
+    check against the single-rank reference and the batches>0 /
     coll_pools>0 floors keep the stress honest."""
     import threading
     import time
@@ -1036,7 +953,6 @@ def tp_decode_churn(workers: int, port: int, max_new: int = 5,
     from parsec_tpu.serve import InferenceEngine, PagedLM, PagedLMConfig
 
     env = dict(env or {})
-    env.setdefault("PTC_MCA_device_wave_fuse", "1")
     saved = {k: os.environ.get(k) for k in env}
     os.environ.update(env)
     errs = []
@@ -1088,8 +1004,7 @@ def tp_decode_churn(workers: int, port: int, max_new: int = 5,
                     rd.join(timeout=10)
                     tp_st = eng._tp_stats()
                     assert tp_st["coll_pools"] > 0, tp_st
-                    fuse = ctx.device_stats().get("fuse", {})
-                    assert fuse.get("fused_waves", 0) > 0, fuse
+                    assert dev.stats["batches"] > 0, dev.stats
                     for h in hs:
                         rt, ro = model.reference_generate(h.prompt,
                                                           h.max_new)
@@ -1242,15 +1157,8 @@ def main():
         # drift observations, interrupts and hot-swaps racing adaptive
         # speculation, budget-share/pressure pushes and stats scrapes
         control_churn(workers=4)
-        # wave mega-kernelization (PR 13): fuse cache + online
-        # certification on the device manager threads, prefetch-lane
-        # peeks, and streamed wire deliveries, 2 colocated ranks
-        wave_fuse_gemm(workers=2, port=30040 + rep,
-                       env={"PTC_MCA_comm_eager_limit": "0",
-                            "PTC_MCA_comm_chunk_size": "2048",
-                            "PTC_MCA_comm_rails": "2"})
         # ptc-shard (PR 18): 2-rank tensor-parallel decode — embedded
-        # RefReduce coll chains + wave compiler + prefetch lane under
+        # RefReduce coll chains + batched waves + prefetch lane under
         # the streamed wire, concurrent stats readers on both ranks
         tp_decode_churn(workers=1, port=30080 + rep,
                         env={"PTC_MCA_comm_eager_limit": "0",
